@@ -31,6 +31,7 @@ from benchmarks import (  # noqa: E402
     bench_uc4_databalance,
 )
 from benchmarks.harness import csv_header, record  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 
 SUITES = {
     "uc1": bench_uc1_routing.main,          # Fig 5 + Table 1 / Fig 6
@@ -77,6 +78,7 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None, choices=sorted(SUITES) + ["dryrun"])
     args = ap.parse_args()
+    enable_compile_cache()
 
     csv_header()
     failures = []

@@ -24,10 +24,6 @@ from typing import Any, Optional
 import jax
 import numpy as np
 
-# Elastic restore re-meshes on load; the launch subsystem's forward-compat
-# polyfills (make_mesh axis_types, AxisType) make that version-portable.
-import repro.kernels.launch  # noqa: F401
-
 
 def _flatten(tree):
     leaves, treedef = jax.tree.flatten(tree)

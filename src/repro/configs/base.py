@@ -55,7 +55,7 @@ class ModelConfig:
     rope_theta: float = 10000.0
     norm_eps: float = 1e-6
     dtype: str = "bfloat16"
-    attention_impl: str = "xla"    # xla | pallas (pallas validated in interpret mode)
+    attention_impl: str = "xla"    # xla | pallas (compiled on TPU, interpreted elsewhere)
     attention_chunk_q: int = 512   # XLA-path q blocking (0 = dense)
     attention_unroll: bool = False  # unroll q chunks (roofline lowering only)
     remat: bool = True

@@ -22,11 +22,6 @@ from typing import Dict, Optional, Sequence, Tuple, Union
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# Anything placing arrays on a mesh goes through here; installing the
-# launch subsystem's jax forward-compat polyfills (make_mesh axis_types,
-# AxisType, shard_map check_vma) keeps mesh construction version-portable.
-import repro.kernels.launch  # noqa: F401
-
 AxisSpec = Union[None, str, Tuple[str, ...]]
 
 

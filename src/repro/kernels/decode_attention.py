@@ -5,7 +5,9 @@ processed together so the (G, D) x (D, Bk) contraction feeds the MXU.
 Grid (B*Hkv, num_kv_blocks), kv innermost with online-softmax scratch.
 Valid-length masking comes from a per-sequence ``lengths`` array so the same
 executable serves any fill level of the cache (no recompilation per step —
-this is the TPU analogue of Hydro's batch-agnostic workers).
+this is the TPU analogue of Hydro's batch-agnostic workers). ``lengths`` is
+a scalar-prefetch operand: it sits in SMEM before the grid runs, and the
+body reads its row's entry as a scalar.
 """
 from __future__ import annotations
 
@@ -22,7 +24,7 @@ NEG_INF = -1e30
 
 def _decode_kernel(
     len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
-    *, scale: float, block_k: int, num_kv_blocks: int,
+    *, scale: float, block_k: int, num_kv_blocks: int, num_kv_heads: int,
 ):
     ki = pl.program_id(1)
 
@@ -32,7 +34,7 @@ def _decode_kernel(
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    length = len_ref[0, 0]
+    length = len_ref[pl.program_id(0) // num_kv_heads]
     k_start = ki * block_k
 
     def _compute():
@@ -82,25 +84,20 @@ def decode_attention_bkgd(
     assert s % block_k == 0, (s, block_k)
     nk = s // block_k
     scale = (d ** -0.5) if scale is None else scale
-    lengths2d = lengths.reshape(-1, 1).astype(jnp.int32)
-
     kernel = functools.partial(
-        _decode_kernel, scale=scale, block_k=block_k, num_kv_blocks=nk
+        _decode_kernel, scale=scale, block_k=block_k, num_kv_blocks=nk,
+        num_kv_heads=num_kv_heads,
     )
     return launch.pallas_call(
         kernel,
         name="decode_attention",
         grid=(bh, nk),
         in_specs=[
-            pl.BlockSpec(
-                (1, 1), lambda b, ki, h=num_kv_heads: (b // h, 0),
-                memory_space=launch.SMEM,
-            ),
-            pl.BlockSpec((1, g, d), lambda b, ki: (b, 0, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, ki: (b, ki, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, ki: (b, ki, 0)),
+            pl.BlockSpec((1, g, d), lambda b, ki, lens: (b, 0, 0)),
+            pl.BlockSpec((1, block_k, d), lambda b, ki, lens: (b, ki, 0)),
+            pl.BlockSpec((1, block_k, d), lambda b, ki, lens: (b, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((1, g, d), lambda b, ki: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, g, d), lambda b, ki, lens: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, g, d), q.dtype),
         scratch_shapes=[
             launch.VMEM((g, d), jnp.float32),
@@ -110,4 +107,5 @@ def decode_attention_bkgd(
         dimension_semantics=("parallel", "arbitrary"),
         interpret=interpret,
         rows=bh * g,
-    )(lengths2d, q, k_cache, v_cache)
+        num_scalar_prefetch=1,
+    )(lengths.astype(jnp.int32), q, k_cache, v_cache)

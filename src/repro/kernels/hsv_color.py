@@ -4,7 +4,12 @@ The paper classifies object colors by checking pixel values against HSV
 ranges (e.g. red = (0,50,70)..(9,255,255)). This kernel fuses RGB->HSV
 conversion, range bucketing (first match wins, remainder = 'other') and the
 per-image histogram reduction. Grid (B, num_row_blocks): row blocks innermost
-accumulate the histogram in VMEM scratch; pixels stream HBM->VMEM once.
+accumulate per-color column counts in VMEM scratch; pixels stream HBM->VMEM
+once.
+
+Layout: the wrapper hands the kernel channel PLANES (B, 3, H, W), so every
+vector op works on (rows, W) tiles with W on the lanes; the (C, 6) color
+ranges sit in SMEM and are read as scalars.
 """
 from __future__ import annotations
 
@@ -18,10 +23,10 @@ from repro.kernels import launch
 
 
 def _hsv_kernel(
-    rgb_ref,    # (1, Br, W, 3)
-    rng_ref,    # (C, 6)
-    hist_ref,   # (1, C+1) output
-    acc_ref,    # scratch (1, C+1) f32
+    rgb_ref,    # (1, 3, Br, W) channel planes
+    rng_ref,    # (C, 6) SMEM
+    hist_ref,   # (1, C+1, 1) output
+    acc_ref,    # scratch (C+1, W) f32 per-column counts
     *, num_row_blocks: int, n_colors: int, total_px: int,
 ):
     ri = pl.program_id(1)
@@ -30,8 +35,9 @@ def _hsv_kernel(
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    rgb = rgb_ref[0].astype(jnp.float32)    # (Br, W, 3)
-    r, g, b = rgb[..., 0], rgb[..., 1], rgb[..., 2]
+    r = rgb_ref[0, 0].astype(jnp.float32)   # (Br, W)
+    g = rgb_ref[0, 1].astype(jnp.float32)
+    b = rgb_ref[0, 2].astype(jnp.float32)
     mx = jnp.maximum(jnp.maximum(r, g), b)
     mn = jnp.minimum(jnp.minimum(r, g), b)
     diff = mx - mn
@@ -44,21 +50,26 @@ def _hsv_kernel(
     h = jnp.where(diff == 0, 0.0, h) * 30.0
     s = jnp.where(mx == 0, 0.0, diff / jnp.where(mx == 0, 1.0, mx)) * 255.0
     v = mx
-    hsv = jnp.stack([h, s, v], axis=-1)     # (Br, W, 3)
 
-    px = hsv[:, :, None, :]                  # (Br, W, 1, 3)
-    lo = rng_ref[...][None, None, :, 0:3]
-    hi = rng_ref[...][None, None, :, 3:6]
-    inrange = jnp.all((px >= lo) & (px <= hi), axis=-1)  # (Br, W, C)
-    first = jnp.cumsum(inrange, axis=-1) == 1
-    inrange = inrange & first
-    other = ~jnp.any(inrange, axis=-1, keepdims=True)
-    onehot = jnp.concatenate([inrange, other], axis=-1).astype(jnp.float32)
-    acc_ref[...] += jnp.sum(onehot, axis=(0, 1))[None] / total_px
+    taken = jnp.zeros(h.shape, jnp.bool_)
+    for c in range(n_colors):  # first matching range wins, in order
+        inrange = (
+            (h >= rng_ref[c, 0]) & (h <= rng_ref[c, 3])
+            & (s >= rng_ref[c, 1]) & (s <= rng_ref[c, 4])
+            & (v >= rng_ref[c, 2]) & (v <= rng_ref[c, 5])
+        )
+        hit = inrange & ~taken
+        taken = taken | inrange
+        acc_ref[c:c + 1, :] += jnp.sum(hit.astype(jnp.float32), axis=0,
+                                       keepdims=True)
+    acc_ref[n_colors:n_colors + 1, :] += jnp.sum(
+        (~taken).astype(jnp.float32), axis=0, keepdims=True)
 
     @pl.when(ri == num_row_blocks - 1)
     def _final():
-        hist_ref[...] = acc_ref[...].astype(hist_ref.dtype)
+        hist_ref[0] = (
+            jnp.sum(acc_ref[...], axis=1, keepdims=True) / total_px
+        ).astype(hist_ref.dtype)
 
 
 def hsv_color_hist(
@@ -77,18 +88,21 @@ def hsv_color_hist(
     kernel = functools.partial(
         _hsv_kernel, num_row_blocks=nr, n_colors=c, total_px=hh * ww
     )
-    return launch.pallas_call(
+    planes = jnp.transpose(crops.astype(jnp.float32), (0, 3, 1, 2))
+    hist = launch.pallas_call(
         kernel,
         name="hsv_color",
         grid=(b, nr),
         in_specs=[
-            pl.BlockSpec((1, block_rows, ww, 3), lambda bi, ri: (bi, ri, 0, 0)),
-            pl.BlockSpec((c, 6), lambda bi, ri: (0, 0)),
+            pl.BlockSpec((1, 3, block_rows, ww), lambda bi, ri: (bi, 0, ri, 0)),
+            pl.BlockSpec((c, 6), lambda bi, ri: (0, 0),
+                         memory_space=launch.SMEM),
         ],
-        out_specs=pl.BlockSpec((1, c + 1), lambda bi, ri: (bi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, c + 1), jnp.float32),
-        scratch_shapes=[launch.VMEM((1, c + 1), jnp.float32)],
+        out_specs=pl.BlockSpec((1, c + 1, 1), lambda bi, ri: (bi, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, c + 1, 1), jnp.float32),
+        scratch_shapes=[launch.VMEM((c + 1, ww), jnp.float32)],
         dimension_semantics=("parallel", "arbitrary"),
         interpret=interpret,
         rows=b,
-    )(crops.astype(jnp.float32), ranges.astype(jnp.float32))
+    )(planes, ranges.astype(jnp.float32))
+    return hist[:, :, 0]

@@ -1,29 +1,20 @@
-"""Version-portable kernel-launch subsystem (Hydro §3.3).
+"""Kernel-launch subsystem (Hydro §3.3), written for jax 0.9.
 
 The paper's core observation is that UDF execution details must not leak
 into the planner; GRACEFUL makes the same argument for UDF execution
-internals sitting behind a uniform costed interface. Before this module,
-every Pallas kernel hard-coded its own backend-specific launch path (six
-copies of the pallas/interpret/XLA dispatch and of the
-``pltpu.CompilerParams`` spelling), which is exactly what broke under the
-pinned JAX. This module owns all of it:
+internals sitting behind a uniform costed interface. Every Pallas kernel
+launches through this module instead of hard-coding its own
+pallas/interpret/XLA dispatch:
 
-(a) **Compat shim** — resolves the JAX API surface that moved between the
-    pinned 0.4.37 and newer releases:
-
-      * ``pltpu.TPUCompilerParams`` (<= 0.4.x) vs ``pltpu.CompilerParams``
-      * ``jax.make_mesh(..., axis_types=...)`` / ``jax.sharding.AxisType``
-      * ``jax.shard_map(..., check_vma=...)`` vs
-        ``jax.experimental.shard_map.shard_map(..., check_rep=...)``
-
-    ``install_forward_compat()`` (run at import) also *polyfills* the newer
-    public names onto the ``jax`` namespace, so code written against newer
-    JAX — including the tier-1 test scripts — runs unchanged on the pinned
-    version. On newer JAX every polyfill is a no-op.
+(a) **Compiler parameters** — ``compiler_params`` builds
+    ``pltpu.CompilerParams``; ``VMEM``/``SMEM`` are re-exported so kernel
+    files never touch ``pltpu`` directly.
 
 (b) **Unified launch wrapper** — ``pallas_call`` is the single launch path
-    for every kernel: compiled Pallas on TPU, interpreter elsewhere, with
-    ONE ``interpret`` knob (None = auto) instead of six copies.
+    for every kernel: compiled by Mosaic on a TPU backend, the Pallas
+    interpreter elsewhere, with ONE ``interpret`` knob (None = auto).
+    ``num_scalar_prefetch`` passes leading arguments as scalar-prefetch
+    operands (SMEM values the index maps and body can read).
     ``resolve_impl`` centralizes the pallas/XLA-reference backend choice
     for the public ops wrappers (the XLA oracle is the dry-run path whose
     FLOPs XLA ``cost_analysis()`` can see).
@@ -49,162 +40,44 @@ pinned JAX. This module owns all of it:
 """
 from __future__ import annotations
 
-import enum
 import functools
-import inspect
 import threading
 import time
 from contextlib import contextmanager
 from dataclasses import dataclass
-from typing import Any, Callable, List, Optional, Sequence
+from typing import Callable, List, Optional, Sequence
 
 import jax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
-    "AxisType", "CompilerParams", "LaunchEvent", "SMEM", "VMEM",
+    "CompilerParams", "LaunchEvent", "SMEM", "VMEM",
     "add_launch_hook", "clear_launch_context", "compiler_params",
     "connect_stats_board", "current_launch_context", "default_interpret",
-    "install_forward_compat", "launch_context", "launch_hooks",
-    "make_mesh", "pallas_call", "remove_launch_hook",
-    "resolve_compiler_params_cls", "resolve_impl", "set_launch_context",
-    "shard_map", "stats_board_hook",
+    "launch_context", "launch_hooks", "pallas_call", "remove_launch_hook",
+    "resolve_impl", "roll", "set_launch_context", "stats_board_hook",
 ]
 
 
 # --------------------------------------------------------------------------- #
-# (a) compat shim                                                             #
+# (a) compiler parameters                                                     #
 # --------------------------------------------------------------------------- #
-def resolve_compiler_params_cls(mod: Any = pltpu) -> type:
-    """Resolve the TPU compiler-params class across JAX versions.
+CompilerParams = pltpu.CompilerParams
 
-    Newer JAX spells it ``CompilerParams``; the pinned 0.4.x line spells it
-    ``TPUCompilerParams``. ``mod`` is injectable for tests."""
-    cls = getattr(mod, "CompilerParams", None)
-    if cls is None:
-        cls = getattr(mod, "TPUCompilerParams", None)
-    if cls is None:
-        raise AttributeError(
-            "pallas tpu module exposes neither CompilerParams nor "
-            "TPUCompilerParams"
-        )
-    return cls
-
-
-CompilerParams = resolve_compiler_params_cls()
-
-# Memory spaces, re-exported so kernel files never touch pltpu directly.
+# Memory spaces and the lane/sublane rotate, re-exported so kernel files
+# never touch pltpu directly. ``roll`` has ``jnp.roll`` semantics for a
+# non-negative shift and lowers to the TPU's vector rotate.
 VMEM = pltpu.VMEM
 SMEM = pltpu.SMEM
+roll = pltpu.roll
 
 
 def compiler_params(dimension_semantics: Optional[Sequence[str]] = None, **kw):
-    """Build compiler params under whichever spelling this JAX provides."""
+    """``pltpu.CompilerParams`` with ``dimension_semantics`` as a tuple."""
     if dimension_semantics is not None:
         kw["dimension_semantics"] = tuple(dimension_semantics)
     return CompilerParams(**kw)
-
-
-class _AxisTypePolyfill(enum.Enum):
-    """Stand-in for ``jax.sharding.AxisType`` (added after 0.4.37).
-
-    The pinned ``jax.make_mesh`` has no axis-type concept — every axis
-    behaves as Auto — so the members only need to exist and be distinct."""
-
-    Auto = "auto"
-    Explicit = "explicit"
-    Manual = "manual"
-
-
-AxisType = getattr(jax.sharding, "AxisType", _AxisTypePolyfill)
-
-
-def _accepts_kwarg(fn: Callable, name: str) -> bool:
-    try:
-        params = inspect.signature(fn).parameters
-    except (TypeError, ValueError):
-        return True  # unsignaturable builtin: optimistically assume yes
-    if any(p.kind is inspect.Parameter.VAR_KEYWORD for p in params.values()):
-        return True
-    return name in params
-
-
-_ORIG_MAKE_MESH = jax.make_mesh
-
-
-def make_mesh(axis_shapes, axis_names, *, devices=None, axis_types=None,
-              **kw):
-    """``jax.make_mesh`` that tolerates ``axis_types`` on any version.
-
-    On JAX without axis types, ``axis_types`` is accepted and ignored
-    (every axis is Auto there, which is what all call sites request)."""
-    if devices is not None:
-        kw["devices"] = devices
-    if axis_types is not None and _accepts_kwarg(_ORIG_MAKE_MESH, "axis_types"):
-        kw["axis_types"] = axis_types
-    return _ORIG_MAKE_MESH(axis_shapes, axis_names, **kw)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None,
-              check_rep=None, **kw):
-    """``jax.shard_map`` across versions.
-
-    Newer JAX exposes ``jax.shard_map(..., check_vma=...)``; 0.4.x has
-    ``jax.experimental.shard_map.shard_map(..., check_rep=...)``. Either
-    keyword is accepted here and translated."""
-    check = True
-    if check_vma is not None:
-        check = check_vma
-    elif check_rep is not None:
-        check = check_rep
-    native = getattr(jax, "shard_map", None)
-    if native is not None and native is not shard_map:
-        return native(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=check, **kw)
-    from jax.experimental.shard_map import shard_map as _esm
-
-    return _esm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_rep=check, **kw)
-
-
-def cost_analysis_dict(compiled) -> dict:
-    """``compiled.cost_analysis()`` across versions.
-
-    JAX <= 0.4.x returns ``list[dict]`` (one per computation); newer JAX
-    returns the dict directly. Always returns a dict here."""
-    out = compiled.cost_analysis()
-    if isinstance(out, (list, tuple)):
-        out = out[0] if out else {}
-    return dict(out or {})
-
-
-def install_forward_compat() -> None:
-    """Polyfill the newer JAX public names onto the pinned version.
-
-    No-op on JAX that already has them. This is what lets downstream code
-    (and the test suite) written against newer JAX run on 0.4.37."""
-    if not hasattr(jax.sharding, "AxisType"):
-        jax.sharding.AxisType = _AxisTypePolyfill
-    if not _accepts_kwarg(_ORIG_MAKE_MESH, "axis_types"):
-        jax.make_mesh = make_mesh
-    if not hasattr(jax, "shard_map"):
-        jax.shard_map = shard_map
-    compiled_cls = jax.stages.Compiled
-    if not getattr(compiled_cls.cost_analysis, "_repro_compat", False):
-        orig = compiled_cls.cost_analysis
-
-        def _cost_analysis(self):
-            out = orig(self)
-            if isinstance(out, (list, tuple)):
-                out = out[0] if out else {}
-            return out
-
-        _cost_analysis._repro_compat = True
-        compiled_cls.cost_analysis = _cost_analysis
-
-
-install_forward_compat()
 
 
 # --------------------------------------------------------------------------- #
@@ -235,29 +108,34 @@ def pallas_call(
     compiler_kwargs: Optional[dict] = None,
     interpret: Optional[bool] = None,
     rows: Optional[int] = None,
+    num_scalar_prefetch: int = 0,
 ):
     """The single kernel-launch path for every Pallas kernel in the repo.
 
     ``interpret=None`` auto-selects: compiled Pallas on TPU, the Pallas
     interpreter elsewhere (how kernels are validated on CPU CI). ``rows``
     is the row count reported to timing hooks (defaults to the leading dim
-    of the first output)."""
+    of the first output). ``name`` also names the kernel in jaxprs and
+    profiler traces. With ``num_scalar_prefetch=n`` the first ``n``
+    call arguments are scalar-prefetch operands: the kernel and every
+    index map receive their refs ahead of the blocked operands."""
     if interpret is None:
         interpret = default_interpret()
-    kw = {}
-    if scratch_shapes is not None:
-        kw["scratch_shapes"] = scratch_shapes
     launched = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=num_scalar_prefetch,
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=out_specs,
+            scratch_shapes=scratch_shapes or (),
+        ),
         out_shape=out_shape,
+        name=name,
         compiler_params=compiler_params(
             dimension_semantics=dimension_semantics, **(compiler_kwargs or {})
         ),
         interpret=interpret,
-        **kw,
     )
     backend = "interpret" if interpret else "pallas"
     if rows is None:

@@ -25,8 +25,8 @@ from repro.kernels import launch
 
 def _ssd_kernel(
     x_ref,    # (1, 1, L, P)
-    dt_ref,   # (1, 1, L)
-    a_ref,    # (1,) SMEM
+    dt_ref,   # (1, 1, L, 1)
+    a_ref,    # (H,) SMEM
     b_ref,    # (1, 1, L, N)
     c_ref,    # (1, 1, L, N)
     h0_ref,   # (1, 1, P, N)
@@ -35,6 +35,7 @@ def _ssd_kernel(
     h_ref,    # scratch (P, N) f32
     *, num_chunks: int, chunk: int,
 ):
+    hi = pl.program_id(1)
     ci = pl.program_id(2)
 
     @pl.when(ci == 0)
@@ -42,47 +43,51 @@ def _ssd_kernel(
         h_ref[...] = h0_ref[0, 0].astype(jnp.float32)
 
     x = x_ref[0, 0].astype(jnp.float32)    # (L, P)
-    dt = dt_ref[0, 0].astype(jnp.float32)  # (L,)
-    A = a_ref[0]                            # scalar
+    dt = dt_ref[0, 0].astype(jnp.float32)  # (L, 1)
+    A = a_ref[hi]                           # scalar
     B = b_ref[0, 0].astype(jnp.float32)    # (L, N)
     C = c_ref[0, 0].astype(jnp.float32)    # (L, N)
 
-    dA = dt * A                             # (L,) log-decay per step
-    dA_cum = jnp.cumsum(dA)                 # (L,)
+    dA = dt * A                             # (L, 1) log-decay per step
+    row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    tri = row >= col                        # [l, m]: m <= l
+    exact = dict(preferred_element_type=jnp.float32,
+                 precision=jax.lax.Precision.HIGHEST)
+    # prefix sums as triangular matmuls (no cumsum or transpose in the
+    # kernel): dA_cum[l] as a column, and dA_cum[m] broadcast along rows
+    dA_cum = jax.lax.dot_general(           # (L, 1)
+        tri.astype(jnp.float32), dA, (((1,), (0,)), ((), ())), **exact)
+    dA_cum_m = jax.lax.dot_general(         # (L, L): [l, m] = dA_cum[m]
+        jnp.ones((chunk, chunk), jnp.float32),
+        jnp.where(row <= col, dA, 0.0), (((1,), (0,)), ((), ())), **exact)
 
     # intra-chunk decay matrix L[l, m] = exp(sum_{m<r<=l} dA_r), lower-tri
-    seg = dA_cum[:, None] - dA_cum[None, :]
-    tri = (
-        jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-        >= jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    )
-    Lmat = jnp.where(tri, jnp.exp(seg), 0.0)
+    Lmat = jnp.where(tri, jnp.exp(dA_cum - dA_cum_m), 0.0)
 
     scores = jax.lax.dot_general(            # C B^T: (L, L)
         C, B, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )
-    att = scores * Lmat * dt[None, :]
-    xdt = x                                   # dt applied via att column scale
-    y = jax.lax.dot_general(                  # (L, P)
-        att, xdt, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+    y = jax.lax.dot_general(                  # (L, P); dt scales x's rows
+        scores * Lmat, x * dt, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
     )
 
     # inter-chunk: contribution of the state entering this chunk
-    in_decay = jnp.exp(dA_cum)                # (L,)
     ch = jax.lax.dot_general(                 # C h_in: (L, P)
         C, h_ref[...], (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
-    y = y + in_decay[:, None] * ch
+    y = y + jnp.exp(dA_cum) * ch
     y_ref[0, 0] = y.astype(y_ref.dtype)
 
     # state update: h_out = total_decay * h_in + sum_l end_decay_l dt_l x_l B_l^T
-    end_decay = jnp.exp(dA_cum[-1] - dA_cum)  # (L,)
-    xw = x * (dt * end_decay)[:, None]        # (L, P)
-    hb = jax.lax.dot_general(                 # (P, N)
+    total = jnp.sum(dA, axis=0, keepdims=True)   # (1, 1)
+    xw = x * (dt * jnp.exp(total - dA_cum))      # (L, P)
+    hb = jax.lax.dot_general(                    # (P, N)
         xw, B, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
-    h_ref[...] = jnp.exp(dA_cum[-1]) * h_ref[...] + hb
+    h_ref[...] = jnp.exp(total) * h_ref[...] + hb
 
     @pl.when(ci == num_chunks - 1)
     def _final():
@@ -113,8 +118,8 @@ def ssd_bhcp(
         grid=(b, h, nc),
         in_specs=[
             pl.BlockSpec((1, 1, chunk, p), lambda bi, hi, ci: (bi, hi, ci, 0)),
-            pl.BlockSpec((1, 1, chunk), lambda bi, hi, ci: (bi, hi, ci)),
-            pl.BlockSpec((1,), lambda bi, hi, ci: (hi,), memory_space=launch.SMEM),
+            pl.BlockSpec((1, 1, chunk, 1), lambda bi, hi, ci: (bi, hi, ci, 0)),
+            pl.BlockSpec((h,), lambda bi, hi, ci: (0,), memory_space=launch.SMEM),
             pl.BlockSpec((1, 1, chunk, n), lambda bi, hi, ci, r=rep: (bi, hi // r, ci, 0)),
             pl.BlockSpec((1, 1, chunk, n), lambda bi, hi, ci, r=rep: (bi, hi // r, ci, 0)),
             pl.BlockSpec((1, 1, p, n), lambda bi, hi, ci: (bi, hi, 0, 0)),
@@ -131,5 +136,5 @@ def ssd_bhcp(
         dimension_semantics=("parallel", "parallel", "arbitrary"),
         interpret=interpret,
         rows=b * s,
-    )(x, dt, A.astype(jnp.float32), Bm, Cm, h0)
+    )(x, dt.reshape(b, h, s, 1), A.astype(jnp.float32), Bm, Cm, h0)
     return y, hlast

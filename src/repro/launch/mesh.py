@@ -8,13 +8,11 @@ import; everything else sees the real device count.
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-from repro.kernels.launch import AxisType, make_mesh
+from jax.sharding import AxisType, Mesh
 
 
 def _mk(shape, axes) -> Mesh:
-    return make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -111,11 +111,9 @@ def run(arch: str, shape_name: str, variant: str, *, outdir: str,
     if mesh_shape is None:
         mesh = make_production_mesh(multi_pod=False)
     else:
-        from repro.kernels.launch import AxisType, make_mesh
-
-        mesh = make_mesh(
+        mesh = jax.make_mesh(
             tuple(mesh_shape), ("data", "model"),
-            axis_types=(AxisType.Auto,) * 2,
+            axis_types=(jax.sharding.AxisType.Auto,) * 2,
         )
     chips = mesh_chip_count(mesh)
 

@@ -417,6 +417,9 @@ class QueryService:
                 "unquarantined": sorted(
                     n for n, s in fsnap.items() if s.get("unquarantines")
                 ),
+                "degraded": sorted(
+                    n for n, s in fsnap.items() if s.get("degraded")
+                ),
                 "failures": sum(s.get("failures", 0) for s in fsnap.values()),
                 "retries": sum(s.get("retries", 0) for s in fsnap.values()),
                 "passthrough_batches": sum(
@@ -534,6 +537,34 @@ class QueryService:
 
 
 # ----------------------------- single-query CLI ----------------------------- #
+def llm_scorer(cfg, params):
+    """Review scorer ``tokens -> scores``: a decoder forward, then the mean
+    pooled log-probability of food words minus that of service words.
+
+    ``params`` is bound as an argument of the jitted function, not closed
+    over, so the weights are never baked into the program as constants."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.data.text import FOOD_WORDS, SERVICE_WORDS
+    from repro.models import transformer as tf
+
+    food = jnp.asarray(FOOD_WORDS)
+    service = jnp.asarray(SERVICE_WORDS)
+
+    @jax.jit
+    def score(params, tokens):  # tokens: (rows, MAX_LEN) int32, 0-padded
+        batch = {"tokens": tokens, "labels": tokens}
+        logits = tf.forward(cfg, params, batch)  # (rows, L, V)
+        mask = (tokens > 0)[..., None].astype(logits.dtype)
+        pooled = (jax.nn.log_softmax(logits.astype(jnp.float32), -1) * mask).sum(1)
+        return pooled[:, food].mean(-1) - pooled[:, service].mean(-1)
+
+    return functools.partial(score, params)
+
+
 def build_llm_udf(arch: str = "smollm-135m", params=None, cfg=None):
     """The LLM(...) predicate: a real decoder forward + token-pool scoring."""
     import jax
@@ -541,26 +572,12 @@ def build_llm_udf(arch: str = "smollm-135m", params=None, cfg=None):
 
     from repro.configs import get_config
     from repro.core.udf import UDF
-    from repro.data.text import FOOD_WORDS, SERVICE_WORDS
     from repro.models.registry import model_api
 
     cfg = cfg or get_config(arch).reduce_for_smoke()
-    api = model_api(cfg)
     if params is None:
-        params = api.init_params(cfg, jax.random.key(0))
-
-    food = jnp.asarray(FOOD_WORDS)
-    service = jnp.asarray(SERVICE_WORDS)
-
-    @jax.jit
-    def score(tokens):  # (rows, MAX_LEN) int32, 0-padded
-        batch = {"tokens": tokens, "labels": tokens}
-        from repro.models import transformer as tf
-
-        logits = tf.forward(cfg, params, batch)  # (rows, L, V)
-        mask = (tokens > 0)[..., None].astype(logits.dtype)
-        pooled = (jax.nn.log_softmax(logits.astype(jnp.float32), -1) * mask).sum(1)
-        return pooled[:, food].mean(-1) - pooled[:, service].mean(-1)
+        params = model_api(cfg).init_params(cfg, jax.random.key(0))
+    score = llm_scorer(cfg, params)
 
     def fn(data):
         return np.asarray(score(jnp.asarray(data["tokens"])))
@@ -589,12 +606,14 @@ def main() -> None:
     the one-off path and the multi-tenant path share one implementation."""
     from repro.core.plan import Query, TrivialPredicate, batches_of
     from repro.core.policies import EDDY_POLICIES, DataAware
+    from repro.launch.compile_cache import enable_compile_cache
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--reviews", type=int, default=200)
     ap.add_argument("--policy", default="cost", choices=sorted(EDDY_POLICIES))
     ap.add_argument("--batch-rows", type=int, default=10)
     args = ap.parse_args()
+    enable_compile_cache()
 
     from repro.data.text import make_reviews
 

@@ -18,7 +18,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ops, ref
-from repro.kernels.launch import shard_map
 from repro.models.layers import ShardCtx, rope
 
 
@@ -173,7 +172,7 @@ def decode_attention_seqsharded(
     ks = P(bspec, None, None)
     ls = P(bspec)
     fn = partial(_local_decode, seq_per_shard=s // model_size, axis="model")
-    out, kc, vc = shard_map(
+    out, kc, vc = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(qs, cs, cs, ks, ks, ls),
@@ -250,7 +249,7 @@ def _ring_decode(cfg, q, cache_k, cache_v, new_k, new_v, lengths, window, ctx):
         out = _partial_softmax_attend(q, kc, vc, local_valid, "model")
         return out, kc, vc
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(qs, cs, cs, ks, ks, ls, ls),
         out_specs=(qs, cs, cs),

@@ -28,7 +28,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ref as kref
-from repro.kernels.launch import shard_map
 from repro.models import transformer as tf
 from repro.models.layers import NULL_CTX, ShardCtx, dtype_of, rms_norm, swiglu_mlp
 from repro.distributed.sharding import spec_for
@@ -249,7 +248,7 @@ def moe_ffn(cfg, lp, x, ctx: ShardCtx):
         cap2 = _capacity(cfg, tokens_global)
         fn = partial(_moe_local, cfg=cfg, capacity=cap2,
                      axis=("data", "model"), ep=True, expert_axis="data")
-        y, aux = shard_map(
+        y, aux = jax.shard_map(
             fn,
             mesh=mesh,
             in_specs=(P(None, None, None), rs,
@@ -271,7 +270,7 @@ def moe_ffn(cfg, lp, x, ctx: ShardCtx):
         ws_gu = P(None, None, "model")
         ws_d = P(None, "model", None)
     fn = partial(_moe_local, cfg=cfg, capacity=capacity, axis="model", ep=ep)
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(xs, rs, ws_gu, ws_gu, ws_d),

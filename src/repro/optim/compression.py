@@ -21,11 +21,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-# compressed_psum runs inside shard_map over a mesh; pull in the launch
-# subsystem's jax forward-compat polyfills (make_mesh axis_types, AxisType,
-# shard_map check_vma) so mesh construction works on the pinned JAX.
-import repro.kernels.launch  # noqa: F401
-
 
 def _quantize(x: jax.Array):
     amax = jnp.max(jnp.abs(x)) + 1e-12

@@ -133,9 +133,7 @@ class CostSample:
 
     @staticmethod
     def from_compiled(compiled, default_group: int, compile_seconds: float = 0.0):
-        from repro.kernels.launch import cost_analysis_dict
-
-        ca = cost_analysis_dict(compiled)
+        ca = compiled.cost_analysis() or {}
         colls = parse_collectives(compiled.as_text(), default_group)
         ma = compiled.memory_analysis()
         mem = {

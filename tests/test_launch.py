@@ -1,92 +1,22 @@
-"""Unit tests for the version-portable kernel-launch subsystem
-(repro.kernels.launch): compat shim resolution under both JAX API
-spellings, mesh construction portability, launch timing hooks feeding
-StatsBoard, and the no-direct-pallas_call invariant over kernel files.
+"""Unit tests for the kernel-launch subsystem (repro.kernels.launch):
+compiler parameters, launch timing hooks feeding StatsBoard, and the
+no-direct-pallas_call invariant over kernel files.
 """
 import os
-import types
 
 import jax
 import jax.numpy as jnp
-import numpy as np
-import pytest
 
 from repro.kernels import launch
 from repro.kernels import ops
 from repro.core.stats import StatsBoard
 
 
-# ------------------------------ compat shim ------------------------------- #
-class _Params:
-    def __init__(self, **kw):
-        self.kw = kw
-
-
-def test_compiler_params_new_spelling():
-    mod = types.SimpleNamespace(CompilerParams=_Params)
-    assert launch.resolve_compiler_params_cls(mod) is _Params
-
-
-def test_compiler_params_old_spelling():
-    mod = types.SimpleNamespace(TPUCompilerParams=_Params)
-    assert launch.resolve_compiler_params_cls(mod) is _Params
-
-
-def test_compiler_params_new_spelling_wins_over_old():
-    class Old(_Params):
-        pass
-
-    mod = types.SimpleNamespace(CompilerParams=_Params, TPUCompilerParams=Old)
-    assert launch.resolve_compiler_params_cls(mod) is _Params
-
-
-def test_compiler_params_neither_spelling_raises():
-    with pytest.raises(AttributeError):
-        launch.resolve_compiler_params_cls(types.SimpleNamespace())
-
-
+# --------------------------- compiler parameters --------------------------- #
 def test_compiler_params_builds_dimension_semantics():
     params = launch.compiler_params(dimension_semantics=["parallel", "arbitrary"])
     assert isinstance(params, launch.CompilerParams)
     assert params.dimension_semantics == ("parallel", "arbitrary")
-
-
-def test_make_mesh_accepts_axis_types_on_any_version():
-    mesh = launch.make_mesh(
-        (1,), ("data",), axis_types=(launch.AxisType.Auto,)
-    )
-    assert mesh.axis_names == ("data",)
-
-
-def test_forward_compat_polyfills_installed():
-    # the polyfills are what let test scripts written against newer JAX
-    # (jax.make_mesh(axis_types=...), jax.shard_map(check_vma=...)) run
-    # unchanged on the pinned version
-    assert hasattr(jax.sharding, "AxisType")
-    mesh = jax.make_mesh((1,), ("data",),
-                         axis_types=(jax.sharding.AxisType.Auto,))
-    assert mesh.devices.size == 1
-    assert hasattr(jax, "shard_map")
-
-
-def test_shard_map_compat_check_vma():
-    from jax.sharding import PartitionSpec as P
-
-    mesh = launch.make_mesh((1,), ("data",))
-    f = launch.shard_map(
-        lambda x: jax.lax.psum(x, "data"),
-        mesh=mesh, in_specs=P(None), out_specs=P(None), check_vma=False,
-    )
-    np.testing.assert_allclose(np.asarray(f(jnp.ones((4,)))), 1.0)
-
-
-def test_cost_analysis_dict_both_shapes():
-    compiled_list = types.SimpleNamespace(cost_analysis=lambda: [{"flops": 2.0}])
-    compiled_dict = types.SimpleNamespace(cost_analysis=lambda: {"flops": 3.0})
-    compiled_none = types.SimpleNamespace(cost_analysis=lambda: None)
-    assert launch.cost_analysis_dict(compiled_list) == {"flops": 2.0}
-    assert launch.cost_analysis_dict(compiled_dict) == {"flops": 3.0}
-    assert launch.cost_analysis_dict(compiled_none) == {}
 
 
 # ------------------------------ launch path ------------------------------- #

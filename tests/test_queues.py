@@ -162,10 +162,11 @@ def test_watermark_fairness_under_concurrency():
         assert q.put_pull(_Item(i), timeout=0.1)
 
     blocked = threading.Event()
+    admitted = []
 
     def pull_ingest():
         blocked.set()
-        q.put_pull(_Item(99), timeout=5.0)  # parked at the watermark
+        admitted.append(q.put_pull(_Item(99), timeout=5.0))  # parked
 
     t = threading.Thread(target=pull_ingest)
     t.start()
@@ -185,9 +186,13 @@ def test_watermark_fairness_under_concurrency():
         w.join(timeout=5)
     assert len(done) == 6                    # none of them blocked
     assert time.monotonic() - t0 < 1.0       # ... and none of them waited
-    q.get(timeout=0.5, shard=0)              # drain one: pull admitted
+    # the watermark counts every queued item: drain 9 -> 2, below the
+    # pull limit of 3, and the parked pull is admitted
+    for _ in range(7):
+        q.get(timeout=0.5, shard=0)
     t.join(timeout=5)
     assert not t.is_alive()
+    assert admitted == [True]
 
 
 def test_close_wakes_pull_blocked_at_watermark():

@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 from repro.core import AQPExecutor, Predicate, UDF, make_batch
+from repro.core.faults import FaultConfig
 from repro.core.statstore import StatsStore, fingerprint_of
 from repro.launch.serve import (
     AdmissionError,
@@ -89,6 +90,24 @@ def test_failed_query_raises_and_keeps_report():
             h.result(timeout=30)
     assert h.report.state == "FAILED"
     assert svc.snapshot()["failed"] == 1
+
+
+def test_report_names_degraded_predicates():
+    def broken(d):
+        raise RuntimeError("compiled path broken")
+
+    udf = UDF("d_udf", fn=broken, columns=("x",), bucket=False,
+              fallback_fn=lambda d: d["x"] >= 0)
+    pd = Predicate("pd", udf, compare=lambda o: o.astype(bool))
+    cfg = FaultConfig(mode="degrade", max_attempts=4, degrade_after=2,
+                      backoff_base_s=0.0, jitter=0.0)
+    with QueryService(max_concurrent=1) as svc:
+        rep = svc.submit([pd], iter(_batches(np.arange(8))), on_fault=cfg,
+                         **_EXEC_KW).result(timeout=30)
+    assert rep.state == "DONE"
+    assert rep.faults["degraded"] == ["pd"]
+    assert rep.faults["failures"] == 2
+    assert Counter(map(int, rep.row_ids)) == Counter(range(8))
 
 
 # --------------------------------------------------------------------------- #
