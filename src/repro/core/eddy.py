@@ -78,6 +78,9 @@ SHARD_AUTO_MAX = 4
 SHARD_AUTO_THRESHOLD_BPS = 200.0
 SHARD_AUTO_MIN_COMPLETED = 64
 
+SOURCE_SPAN = "hydro.source"
+ROUTE_SPAN = "hydro.eddy:route"
+
 
 class InFlightTracker:
     """Atomic in-flight batch count shared by the pull and every shard.
@@ -108,6 +111,9 @@ class InFlightTracker:
             return self._n
 
 
+_END = object()  # the source is exhausted
+
+
 class EddyPull(threading.Thread):
     """Pulls batches from the child iterator into the central queue."""
 
@@ -127,7 +133,12 @@ class EddyPull(threading.Thread):
         if self.launch_token is not None:
             kernel_launch.set_launch_context(self.launch_token)
         try:
-            for batch in self.source:
+            batches = iter(self.source)
+            while True:
+                with kernel_launch.span(SOURCE_SPAN):
+                    batch = next(batches, _END)
+                if batch is _END:
+                    break
                 # count BEFORE the queue insert: a batch is in flight from
                 # the moment it leaves the source iterator
                 self.tracker.started()
@@ -157,6 +168,10 @@ class EddyShard(threading.Thread):
         self.core = core
         self.completed = 0
         self.circulations = 0
+        # routing decisions and their nanoseconds, hand-off to the worker
+        # queue included (the interval of the ``hydro.eddy:route`` span)
+        self.routed = 0
+        self.route_ns = 0
         self.error: Optional[BaseException] = None
 
     def _route(self, batch: RoutingBatch) -> None:
@@ -166,11 +181,16 @@ class EddyShard(threading.Thread):
         lost, but the termination barrier stays exact, so sibling shards
         and the executor observe completion instead of hanging forever on
         a count that can never reach zero."""
-        try:
-            self._route_inner(batch)
-        except BaseException:
-            self.core.tracker.finished()
-            raise
+        with kernel_launch.span(ROUTE_SPAN):
+            t0 = time.perf_counter_ns()
+            try:
+                self._route_inner(batch)
+            except BaseException:
+                self.core.tracker.finished()
+                raise
+            finally:
+                self.route_ns += time.perf_counter_ns() - t0
+                self.routed += 1
 
     def _route_inner(self, batch: RoutingBatch) -> None:
         core = self.core
@@ -400,6 +420,14 @@ class EddyShardSet:
     @property
     def circulations(self) -> int:
         return sum(s.circulations for s in self._shards)
+
+    @property
+    def routed(self) -> int:
+        return sum(s.routed for s in self._shards)
+
+    @property
+    def route_ns(self) -> int:
+        return sum(s.route_ns for s in self._shards)
 
     @property
     def steals(self) -> int:

@@ -219,8 +219,10 @@ class AQPExecutor:
         self._tracker = InFlightTracker()
         # per-executor launch attribution token: every thread this executor
         # owns tags itself with it, and the run()-lifetime stats hook only
-        # observes launches from so-tagged threads
-        self._launch_token = object()
+        # observes launches from so-tagged threads; it also carries the
+        # query id into the program's spans and collects the lowering and
+        # compiles made on those threads (the "_compile" snapshot key)
+        self._launch_token = kernel_launch.LaunchContext(query)
         # shared arbiter > shared pool > private unbounded pool (the
         # private default reproduces the pre-arbiter per-predicate pools)
         if arbiter is not None and (pool is not None or arbiter_policy is not None):
@@ -431,9 +433,12 @@ class AQPExecutor:
         Predicate entries are keyed by name as before; the reserved
         ``"_arbiter"`` key carries lease/release/denial/handoff counters,
         ``"_routing"`` the shard-set picture (active shards, steals,
-        circulations, completed), and ``"_faults"`` the per-predicate
-        fault ledger (see core/faults.FaultLedger.snapshot for the key
-        contract). The reserved ``"_service"`` key carries the
+        circulations, completed, and ``routed`` routing decisions taking
+        ``route_ns`` nanoseconds), ``"_compile"`` the lowering seconds,
+        compiles and persistent-cache loads made on this executor's
+        threads (``kernels/launch.LaunchContext``), and ``"_faults"`` the
+        per-predicate fault ledger (see core/faults.FaultLedger.snapshot
+        for the key contract). The reserved ``"_service"`` key carries the
         multi-tenant picture: ``{"managed": False}`` for a standalone
         executor, or the managing QueryService's per-query identity
         (query id, priority, deadline — see launch/serve.py) when this
@@ -457,7 +462,10 @@ class AQPExecutor:
             "steals": r.steals if r is not None else 0,
             "circulations": r.circulations if r is not None else 0,
             "completed": r.completed if r is not None else 0,
+            "routed": r.routed if r is not None else 0,
+            "route_ns": r.route_ns if r is not None else 0,
         }
+        snap["_compile"] = self._launch_token.snapshot()
         if self.coalesce_config is not None:
             snap["_coalesce"] = {
                 "mode": self.coalesce_config.mode,
@@ -506,15 +514,13 @@ class QuerySession:
     the consumer abandons the iterator or an evaluation fails — the
     arbiter registration is released, so the same predicate names are
     re-registerable for the next run and the shared DevicePool never
-    leaks slots. The final ``stats_snapshot()`` of each run is kept in
-    ``last_snapshot`` for telemetry."""
+    leaks slots."""
 
     def __init__(self, predicates: List[Predicate], **executor_kwargs):
         self.predicates = predicates
         self.executor_kwargs = executor_kwargs
         self.runs = 0
         self.executor: Optional[AQPExecutor] = None  # live during run()
-        self.last_snapshot = None
 
     def run(self, source: Iterable[RoutingBatch]) -> Iterator[RoutingBatch]:
         """One full query execution on a fresh executor; restartable."""
@@ -526,7 +532,6 @@ class QuerySession:
                 for b in ex.run(source):
                     yield b
         finally:
-            self.last_snapshot = ex.stats_snapshot()
             self.executor = None
 
     def collect(self, source: Iterable[RoutingBatch]) -> List[RoutingBatch]:

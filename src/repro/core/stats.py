@@ -81,6 +81,13 @@ class PredicateStats:
     fused_batches: int = 0
     coalesced_rows: int = 0
 
+    # worker-queue wait: batches taken off a worker queue and the
+    # nanoseconds they sat there; ``counts`` holds what the UDF declares it
+    # counted per call (``UDF.counts``, e.g. real and launched tokens)
+    dequeued: int = 0
+    queue_wait_ns: int = 0
+    counts: Dict[str, int] = field(default_factory=dict)
+
     # launch-cost decomposition moments: EMAs of rows, seconds, rows^2 and
     # rows*seconds over per-launch samples (see module docstring)
     lc_rows: Ema = field(default_factory=lambda: Ema(0.2))
@@ -172,6 +179,16 @@ class PredicateStats:
             self.cache_probes += probes
             self.cache_hits += hits
 
+    def record_dequeue(self, wait_ns: int) -> None:
+        with self._lock:
+            self.dequeued += 1
+            self.queue_wait_ns += wait_ns
+
+    def add_counts(self, counts: Dict[str, int]) -> None:
+        with self._lock:
+            for k, v in counts.items():
+                self.counts[k] = self.counts.get(k, 0) + int(v)
+
     # ------------------------- estimates ------------------------- #
     @property
     def measured(self) -> bool:
@@ -249,6 +266,8 @@ class PredicateStats:
         return self.cost() / max(1.0 - sel, 1e-6)
 
     def snapshot(self) -> Dict[str, float]:
+        with self._lock:
+            counts = dict(self.counts)
         return {
             "cost_per_row": self.cost(),
             "selectivity": self.selectivity(),
@@ -258,6 +277,9 @@ class PredicateStats:
             "launches": self.launches,
             "fused_launches": self.fused_launches,
             "fused_batches": self.fused_batches,
+            "dequeued": self.dequeued,
+            "queue_wait_ns": self.queue_wait_ns,
+            **counts,
         }
 
 
@@ -302,6 +324,12 @@ class ShardedPredicateStats:
 
     def record_cache(self, probes: int, hits: int) -> None:
         self._stripe().record_cache(probes, hits)
+
+    def record_dequeue(self, wait_ns: int) -> None:
+        self._stripe().record_dequeue(wait_ns)
+
+    def add_counts(self, counts: Dict[str, int]) -> None:
+        self._stripe().add_counts(counts)
 
     # ------------------------- merged estimates ------------------------- #
     @property
@@ -416,7 +444,18 @@ class ShardedPredicateStats:
             "launches": self.launches,
             "fused_launches": self.fused_launches,
             "fused_batches": self.fused_batches,
+            "dequeued": sum(s.dequeued for s in self.stripes),
+            "queue_wait_ns": sum(s.queue_wait_ns for s in self.stripes),
+            **self._counts(),
         }
+
+    def _counts(self) -> Dict[str, int]:
+        total: Dict[str, int] = {}
+        for s in self.stripes:
+            with s._lock:
+                for k, v in s.counts.items():
+                    total[k] = total.get(k, 0) + v
+        return total
 
 
 class StatsBoard:

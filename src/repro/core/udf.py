@@ -13,11 +13,22 @@ compilation + weight residency is conservative.
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, Optional, Sequence
 
 import numpy as np
+
+from repro.kernels import launch as kernel_launch
+
+# profiler spans of a UDF call: the whole call, and inside it column
+# preparation and bucket padding here, then host->device transfer, launch
+# and device->host sync inside the UDF functions that run on the device.
+# The call's self time is what falls between those phases.
+CALL_SPAN = "hydro.udf:call"
+PREP_SPAN = "hydro.udf:prep"
+H2D_SPAN = "hydro.udf:h2d"
+LAUNCH_SPAN = "hydro.udf:launch"
+D2H_SPAN = "hydro.udf:d2h"
 
 
 def bucket_rows(n: int, *, minimum: int = 1) -> int:
@@ -71,6 +82,11 @@ class UDF:
     # when the compiled path fails repeatedly. None == nothing to fall
     # back to (degrade-mode fault handling then quarantines instead).
     fallback_fn: Optional[Callable[[Dict[str, np.ndarray]], np.ndarray]] = None
+    # what one call counts, from its real rows' columns and the rows it
+    # launches after bucket padding (e.g. real against launched tokens);
+    # the worker adds it to the predicate's statistics entry
+    counts: Optional[
+        Callable[[Dict[str, np.ndarray], int], Dict[str, int]]] = None
     degraded: bool = field(default=False, repr=False)
     _ready: bool = field(default=False, repr=False)
     # output dtype + trailing shape, learned from the first evaluation so
@@ -121,11 +137,24 @@ class UDF:
         first = data[self.columns[0]]
         return float(np.asarray(first).size)  # default: input size
 
+    def launched_rows(self, rows: int) -> int:
+        """Rows a call over ``rows`` real rows launches: the power-of-two
+        bucket when bucketing."""
+        return bucket_rows(rows) if self.bucket and rows else rows
+
     def __call__(self, data: Dict[str, np.ndarray]) -> np.ndarray:
+        with kernel_launch.span(CALL_SPAN):
+            return self._call(data)
+
+    def _call(self, data: Dict[str, np.ndarray]) -> np.ndarray:
         self.ensure_ready()
         fn = self._active_fn()
-        cols = {c: np.asarray(data[c]) for c in self.columns}
-        rows = len(next(iter(cols.values())))
+        with kernel_launch.span(PREP_SPAN):
+            cols = {c: np.asarray(data[c]) for c in self.columns}
+            rows = len(next(iter(cols.values())))
+            launched = self.launched_rows(rows)
+            if launched != rows:
+                cols = {c: pad_rows(v, launched) for c, v in cols.items()}
         if rows == 0:
             if self._out_spec is None:
                 # Probe with ONE synthesized row, never genuinely empty
@@ -150,9 +179,6 @@ class UDF:
         if not self.bucket:
             out = np.asarray(fn(cols))
         else:
-            b = bucket_rows(rows)
-            if b != rows:
-                cols = {c: pad_rows(v, b) for c, v in cols.items()}
             out = np.asarray(fn(cols))[:rows]
         if out.ndim:
             self._out_spec = (out.dtype, out.shape[1:])
@@ -177,9 +203,3 @@ class Predicate:
 
     def mask_from_outputs(self, outputs: np.ndarray) -> np.ndarray:
         return np.asarray(self.compare(outputs), bool)
-
-
-def timed(fn, *args, **kw):
-    t0 = time.perf_counter()
-    out = fn(*args, **kw)
-    return out, time.perf_counter() - t0

@@ -90,6 +90,8 @@ from repro.core.stats import StatsBoard
 from repro.core.udf import Predicate
 from repro.kernels import launch as kernel_launch
 
+EVAL_SPAN = "hydro.worker:eval"
+
 
 def _checked_outputs(pred, data, rows: int, faults, clock) -> np.ndarray:
     """One evaluation through the (optional) fault-injection seam, with
@@ -175,6 +177,13 @@ def _evaluate_with_cache(pred, batch, data, *, cache, stats, faults=None,
     return outputs, wall, rows, data
 
 
+def _record_counts(pred, stats, computed_rows: int, compute_data) -> None:
+    """What the UDF counts of the rows it computed (``UDF.counts``)."""
+    if pred.udf.counts is not None:
+        stats[pred.name].add_counts(pred.udf.counts(
+            compute_data, pred.udf.launched_rows(computed_rows)))
+
+
 def _sim_cost(pred, computed_rows: int, data, wall: float) -> float:
     if pred.udf.cost_model is None:
         return wall
@@ -236,6 +245,7 @@ def evaluate_predicate(
     # div-by-near-zero on full hits) — full-hit evaluations are skipped.
     if computed_rows and compute_data is not None:
         stats.note_proxy_rate(pred.udf.proxy(compute_data), seconds)
+        _record_counts(pred, stats, computed_rows, compute_data)
     return out_batch
 
 
@@ -293,6 +303,7 @@ def evaluate_fused(
     )
     if computed_rows and compute_data is not None:
         stats.note_proxy_rate(pred.udf.proxy(compute_data), seconds)
+        _record_counts(pred, stats, computed_rows, compute_data)
     return outs
 
 
@@ -478,8 +489,21 @@ class WorkerContext:
             self._thread.start()
 
     def submit(self, batch: RoutingBatch, timeout: Optional[float] = None) -> bool:
+        """Queue ``batch`` for this worker. The time of the put travels
+        beside the batch (``RoutingBatch`` is frozen), so the dequeue can
+        count how long it waited."""
         self.activate()
-        return self.queue.put(batch, timeout)
+        return self.queue.put((time.perf_counter_ns(), batch), timeout)
+
+    def _take(self, item) -> RoutingBatch:
+        """A dequeued ``(put time, batch)``: count its wait, return the
+        batch. On simulated time a wall-clock wait means nothing, and the
+        statistics stay deterministic: the wait counts as none."""
+        stamp, batch = item
+        simulated = getattr(self.clock, "simulated", False)
+        self.stats[self.pred.name].record_dequeue(
+            0 if simulated else time.perf_counter_ns() - stamp)
+        return batch
 
     # ------------------------- coalescing ------------------------- #
     def _drain_coalesce(self, first: RoutingBatch) -> List[RoutingBatch]:
@@ -497,7 +521,8 @@ class WorkerContext:
         batches, rows = [first], first.rows
         deadline = None
         while rows < plan.target_rows and len(batches) < plan.max_batches:
-            got = self.queue.get_many(plan.max_batches - len(batches))
+            got = [self._take(item) for item in
+                   self.queue.get_many(plan.max_batches - len(batches))]
             if got:
                 batches.extend(got)
                 rows += sum(b.rows for b in got)
@@ -511,7 +536,7 @@ class WorkerContext:
             if remaining <= 0:
                 break
             try:
-                batches.append(self.queue.get(timeout=remaining))
+                batches.append(self._take(self.queue.get(timeout=remaining)))
                 rows += batches[-1].rows
             except (TimeoutError, ClosedError):
                 break
@@ -524,6 +549,10 @@ class WorkerContext:
         Zero-row batches never launch anything and take the single-batch
         path (mark-visited only); the non-empty remainder fuses into one
         launch when there are at least two."""
+        with kernel_launch.span(EVAL_SPAN):
+            return self._evaluate_group_inner(batches)
+
+    def _evaluate_group_inner(self, batches: List[RoutingBatch]) -> List[RoutingBatch]:
         fusable = [b for b in batches if b.rows > 0]
         if len(fusable) < 2 or (
             # quarantined: per-batch path so the pass-through / recovery-
@@ -572,7 +601,7 @@ class WorkerContext:
             kernel_launch.set_launch_context(self.launch_token)
         while True:
             try:
-                batch = self.queue.get(timeout=self.idle_timeout)
+                batch = self._take(self.queue.get(timeout=self.idle_timeout))
             except TimeoutError:
                 # queue idle past the drain threshold: offer to retire.
                 # The router decides under its own lock (floor of one

@@ -37,6 +37,15 @@ pallas/interpret/XLA dispatch:
     CONCURRENTLY in one process each record only their own kernel
     launches (per-executor attribution — the old process-global bus
     cross-recorded).
+
+(d) **Spans and compile counters** — an executor's token is a
+    ``LaunchContext``: the query its threads serve, and what lowering and
+    compiling on those threads cost it (one process-wide
+    ``jax.monitoring`` listener charges each event to the context of the
+    thread it happens on). ``span(name)`` opens a
+    ``jax.profiler.TraceAnnotation`` tagged with that query id, so the
+    program's spans land in the profiler's trace on the device's clock; with
+    no trace being taken a span is one small native object.
 """
 from __future__ import annotations
 
@@ -50,13 +59,15 @@ from typing import Callable, List, Optional, Sequence
 import jax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.profiler import TraceAnnotation
 
 __all__ = [
-    "CompilerParams", "LaunchEvent", "SMEM", "VMEM",
+    "CompilerParams", "LaunchContext", "LaunchEvent", "SMEM", "VMEM",
     "add_launch_hook", "clear_launch_context", "compiler_params",
     "connect_stats_board", "current_launch_context", "default_interpret",
     "launch_context", "launch_hooks", "pallas_call", "remove_launch_hook",
-    "resolve_impl", "roll", "set_launch_context", "stats_board_hook",
+    "resolve_impl", "roll", "set_launch_context", "span",
+    "stats_board_hook",
 ]
 
 
@@ -321,3 +332,72 @@ def connect_stats_board(board, *, token=None) -> Callable[[LaunchEvent], None]:
     tagged with that launch context reach ``board`` — how concurrent
     executors keep per-executor attribution."""
     return add_launch_hook(stats_board_hook(board), token=token)
+
+
+# --------------------------------------------------------------------------- #
+# (d) spans and compile counters                                              #
+# --------------------------------------------------------------------------- #
+_LOWER_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
+                 "/jax/core/compile/jaxpr_to_mlir_module_duration")
+# JAX reports a program fetched from the persistent cache as a backend
+# compile too; ``LaunchContext.snapshot`` subtracts the cache loads
+_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_LISTENING = False
+
+
+class LaunchContext:
+    """An executor's launch token: the query its threads serve, and what
+    lowering and compiling on those threads cost it. ``lower_s`` sums the
+    seconds spent tracing jaxprs and lowering them to MLIR; ``compiles``
+    counts backend compiles that were not persistent-cache loads;
+    ``cache_loads`` counts those loads."""
+
+    def __init__(self, query: Optional[str] = None):
+        self.query = query
+        self._lock = threading.Lock()
+        self._lower_s = 0.0
+        self._backend_compiles = 0
+        self._cache_loads = 0
+        _listen_for_compiles()
+
+    def _note(self, event: str, seconds: float = 0.0) -> None:
+        with self._lock:
+            if event in _LOWER_EVENTS:
+                self._lower_s += seconds
+            elif event == _BACKEND_COMPILE_EVENT:
+                self._backend_compiles += 1
+            elif event == _CACHE_HIT_EVENT:
+                self._cache_loads += 1
+
+    def snapshot(self) -> dict:
+        with self._lock:
+            return {"lower_s": self._lower_s,
+                    "compiles": self._backend_compiles - self._cache_loads,
+                    "cache_loads": self._cache_loads}
+
+
+def _charge(event: str, seconds: float = 0.0, **_) -> None:
+    """A ``jax.monitoring`` event, charged to this thread's context."""
+    ctx = current_launch_context()
+    if isinstance(ctx, LaunchContext):
+        ctx._note(event, seconds)
+
+
+def _listen_for_compiles() -> None:
+    """Register the process-wide compile listener, once."""
+    global _LISTENING
+    with _HOOKS_LOCK:
+        if _LISTENING:
+            return
+        _LISTENING = True
+    jax.monitoring.register_event_duration_secs_listener(_charge)
+    jax.monitoring.register_event_listener(_charge)
+
+
+def span(name: str, qid: Optional[str] = None) -> TraceAnnotation:
+    """A profiler span named ``name`` (``hydro.<layer>``), tagged with
+    ``qid`` or else the query of this thread's launch context."""
+    if qid is None:
+        qid = getattr(current_launch_context(), "query", None)
+    return TraceAnnotation(name) if qid is None else TraceAnnotation(name, qid=qid)

@@ -54,10 +54,15 @@ starts from query A's in-flight profile, not from roofline priors.
 Telemetry: each finished handle carries a structured ``QueryReport`` —
 queue-time vs eval-time split, per-predicate cache hit rates, routing
 counters, fault/quarantine summary, re-verification counters (executor
-knob ``reverify=``), exact output row ids — and every tenant executor's
-``stats_snapshot()["_service"]`` identifies its query, priority and
-deadline.  Service threads are daemons named ``svc-dispatch`` /
+knob ``reverify=``), exact output row ids, the dispatcher's deferrals of
+the query, each predicate's statistics entry (worker-queue wait, UDF
+counts) and the lowering and compiles its threads made — and every
+tenant executor's ``stats_snapshot()["_service"]`` identifies its query,
+priority and deadline.  Service threads are daemons named ``svc-dispatch`` /
 ``svc-query-<qid>`` (covered by the tests/conftest leaked-thread guard).
+A query's life on its thread is the profiler span ``hydro.query``, and a
+dispatcher pass that dispatched a query is ``hydro.service:dispatch``,
+both tagged with the query id.
 
 The single-query CLI below is rebuilt ON TOP of the service
 (``max_concurrent=1``) — one driver code path for both modes:
@@ -81,8 +86,12 @@ from repro.core.policies import ArbiterPolicy, urgency_weight
 from repro.core.resources import DevicePool, ResourceArbiter
 from repro.core.statstore import StatsStore
 from repro.core.udf import Predicate
+from repro.kernels import launch as kernel_launch
 
 MAX_LEN = 512
+
+QUERY_SPAN = "hydro.query"
+DISPATCH_SPAN = "hydro.service:dispatch"
 
 # Dispatcher poll cadence: how promptly pending-queue deadline expiry is
 # noticed when no submit/finish event wakes the dispatcher.
@@ -112,7 +121,13 @@ class QueryReport:
     ``board_predicates`` the predicate entries this query's OWN board
     profiled (the cross-query leakage assert: it must only ever contain
     this query's names).  ``routing`` / ``faults`` / ``cache_hit_rates``
-    / ``reverify`` summarize the tenant executor's final snapshot."""
+    / ``reverify`` summarize the tenant executor's final snapshot;
+    ``stats`` holds its predicate entries whole (``dequeued`` and
+    ``queue_wait_ns``, and the counts a UDF declares) and ``compile`` its
+    ``"_compile"`` entry (``lower_s``, ``compiles``, ``cache_loads``).
+    ``dispatch_deferrals`` counts the dispatcher passes that found the
+    query first in line but left it pending behind a running query with a
+    predicate of the same name."""
 
     qid: str
     state: str
@@ -132,6 +147,9 @@ class QueryReport:
     routing: Dict[str, object] = field(default_factory=dict)
     faults: Dict[str, object] = field(default_factory=dict)
     reverify: Optional[Dict[str, int]] = None
+    stats: Dict[str, Dict[str, float]] = field(default_factory=dict)
+    compile: Dict[str, float] = field(default_factory=dict)
+    dispatch_deferrals: int = 0
     error: str = ""
 
 
@@ -278,14 +296,15 @@ class QueryService:
                 handle, predicates, source, kwargs = item
                 handle.report.state = RUNNING
                 self._running[handle.qid] = handle
-            t = threading.Thread(
-                target=self._run_query,
-                args=(handle, predicates, source, kwargs),
-                daemon=True, name=f"svc-query-{handle.qid}",
-            )
-            with self._cv:
-                self._threads.append(t)
-            t.start()
+            with kernel_launch.span(DISPATCH_SPAN, handle.qid):
+                t = threading.Thread(
+                    target=self._run_query,
+                    args=(handle, predicates, source, kwargs),
+                    daemon=True, name=f"svc-query-{handle.qid}",
+                )
+                with self._cv:
+                    self._threads.append(t)
+                t.start()
 
     def _dispatchable_locked(self) -> bool:
         return bool(self._pending) and len(self._running) < self.max_concurrent
@@ -336,6 +355,7 @@ class QueryService:
             item = heapq.heappop(self._pending)
             _, _, _, handle, predicates, _, _ = item
             if {p.name for p in predicates} & running_names:
+                handle.report.dispatch_deferrals += 1
                 deferred.append(item)
                 continue
             picked = item[3:]
@@ -357,6 +377,12 @@ class QueryService:
 
     def _run_query(self, handle: QueryHandle, predicates: List[Predicate],
                    source: Iterable, kwargs: dict) -> None:
+        with kernel_launch.span(QUERY_SPAN, handle.qid):
+            self._run_query_inner(handle, predicates, source, kwargs)
+
+    def _run_query_inner(self, handle: QueryHandle,
+                         predicates: List[Predicate], source: Iterable,
+                         kwargs: dict) -> None:
         report = handle.report
         started = time.monotonic()
         report.started_at = started
@@ -409,6 +435,10 @@ class QueryService:
                 for k, v in snap.items() if not k.startswith("_")
             }
             report.routing = snap.get("_routing", {})
+            report.stats = {
+                k: v for k, v in snap.items() if not k.startswith("_")
+            }
+            report.compile = snap.get("_compile", {})
             fsnap = snap.get("_faults", {})
             report.faults = {
                 "quarantined": sorted(
@@ -571,7 +601,7 @@ def build_llm_udf(arch: str = "smollm-135m", params=None, cfg=None):
     import jax.numpy as jnp
 
     from repro.configs import get_config
-    from repro.core.udf import UDF
+    from repro.core.udf import D2H_SPAN, H2D_SPAN, LAUNCH_SPAN, UDF
     from repro.models.registry import model_api
 
     cfg = cfg or get_config(arch).reduce_for_smoke()
@@ -580,11 +610,22 @@ def build_llm_udf(arch: str = "smollm-135m", params=None, cfg=None):
     score = llm_scorer(cfg, params)
 
     def fn(data):
-        return np.asarray(score(jnp.asarray(data["tokens"])))
+        with kernel_launch.span(H2D_SPAN):
+            tokens = jnp.asarray(data["tokens"])
+        with kernel_launch.span(LAUNCH_SPAN):
+            scores = score(tokens)
+        with kernel_launch.span(D2H_SPAN):
+            return np.asarray(scores)
+
+    def counts(data, launched_rows):
+        tokens = data["tokens"]
+        return {"tokens_real": int((tokens > 0).sum()),
+                "tokens_launched": launched_rows * tokens.shape[1]}
 
     return UDF(
         "LLM", fn, columns=("tokens",), resource="tpu:0",
         proxy_cost=lambda d: float((d["tokens"] > 0).sum()),  # text length
+        counts=counts,
     )
 
 

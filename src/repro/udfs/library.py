@@ -32,7 +32,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.statstore import canonical_fingerprint
-from repro.core.udf import Predicate, UDF
+from repro.core.udf import D2H_SPAN, H2D_SPAN, LAUNCH_SPAN, Predicate, UDF
+from repro.kernels import launch as kernel_launch
 from repro.kernels import ops, ref
 from repro.udfs import rooflines
 
@@ -107,10 +108,13 @@ def color_predicate(
     block_rows = block_divisor(size, 64)
 
     def fn(d):
-        crops = jnp.asarray(np.asarray(d["crop"], np.float32))
-        _, label = ops.hsv_color_classify(crops, impl=impl,
-                                          block_rows=block_rows)
-        return np.asarray(label)
+        with kernel_launch.span(H2D_SPAN):
+            crops = jnp.asarray(np.asarray(d["crop"], np.float32))
+        with kernel_launch.span(LAUNCH_SPAN):
+            _, label = ops.hsv_color_classify(crops, impl=impl,
+                                              block_rows=block_rows)
+        with kernel_launch.span(D2H_SPAN):
+            return np.asarray(label)
 
     name = name or f"color_is_{color}"
     udf = UDF(

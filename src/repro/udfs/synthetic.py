@@ -22,7 +22,8 @@ from typing import Iterable
 import numpy as np
 
 from repro.core.statstore import canonical_fingerprint
-from repro.core.udf import Predicate, UDF
+from repro.core.udf import LAUNCH_SPAN, Predicate, UDF
+from repro.kernels import launch as kernel_launch
 from repro.kernels import ops
 from repro.udfs.library import block_divisor, one_row_probe
 from repro.udfs import rooflines
@@ -109,8 +110,9 @@ def planted_classifier(
 
     def fn(d):
         px = np.asarray(d[pixel_column], np.float32)
-        ops.hsv_color_classify(px, impl=impl,
-                               block_rows=block_divisor(px.shape[1], 64))
+        with kernel_launch.span(LAUNCH_SPAN):
+            ops.hsv_color_classify(px, impl=impl,
+                                   block_rows=block_divisor(px.shape[1], 64))
         return np.asarray(d[label_column])
 
     udf = UDF(
