@@ -8,16 +8,34 @@ tiny same-family config for CPU smoke tests.
 from __future__ import annotations
 
 import dataclasses
+import math
 from dataclasses import dataclass, field
-from typing import Tuple
+from typing import Optional, Tuple
 
 VOCAB_PAD_MULTIPLE = 256
 
 
 @dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rotary scaling, as the ``rope_scaling`` group of a DeepSeek-V2
+    ``config.json`` gives it (``type: yarn``)."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    @staticmethod
+    def get_mscale(scale: float, mscale: float) -> float:
+        return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+@dataclass(frozen=True)
 class ModelConfig:
     name: str
-    family: str  # dense | moe | encdec | hybrid | ssm | vlm
+    family: str  # dense | moe | deepseek | encdec | hybrid | ssm | vlm
     num_layers: int
     d_model: int
     num_heads: int
@@ -31,6 +49,17 @@ class ModelConfig:
     num_experts_per_tok: int = 0
     moe_dense_residual: bool = False  # arctic: parallel dense FFN residual
     capacity_factor: float = 1.25
+    moe_d_ff: int = 0              # routed and shared expert width (0 -> d_ff)
+    num_shared_experts: int = 0    # deepseek: always-on experts, one SwiGLU of moe_d_ff * n
+    first_dense_layers: int = 0    # deepseek: leading layers with a dense d_ff MLP
+    router_renormalize: bool = True  # top-k gate weights renormalised to sum to 1
+
+    # --- multi-head latent attention (deepseek) ---
+    kv_lora_rank: int = 0          # >0: MLA; width of the compressed kv latent
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_scaling: Optional[YarnScaling] = None
 
     # --- attention variants ---
     sliding_window: int = 0        # >0: mistral-style SWA (ring-buffer cache)
@@ -136,6 +165,14 @@ class ModelConfig:
         )
         if self.num_experts:
             kw.update(num_experts=4, num_experts_per_tok=min(2, self.num_experts_per_tok))
+        if self.moe_d_ff:
+            kw.update(moe_d_ff=32)
+        if self.first_dense_layers:
+            kw.update(first_dense_layers=1, num_layers=3)
+        if self.kv_lora_rank:
+            # q/k width 24 against v width 16 keeps MLA's unequal widths
+            kw.update(kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+                      v_head_dim=16, head_dim=24)
         if self.ssm_state:
             kw.update(ssm_state=16, ssm_head_dim=16, ssm_expand=2)
         if self.num_encoder_layers:

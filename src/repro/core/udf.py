@@ -13,6 +13,7 @@ compilation + weight residency is conservative.
 """
 from __future__ import annotations
 
+import threading
 from dataclasses import dataclass, field
 from typing import Callable, Dict, Optional, Sequence
 
@@ -56,6 +57,16 @@ def pad_rows(v: np.ndarray, target: int) -> np.ndarray:
     return out
 
 
+@dataclass(frozen=True)
+class WithExtras:
+    """What a UDF function may return in place of its outputs: the outputs
+    and what the call computed beside them (a model's per-layer expert
+    loads, say), which the UDF's ``counts`` then sees."""
+
+    outputs: np.ndarray
+    extras: Dict[str, np.ndarray]
+
+
 @dataclass
 class UDF:
     """A (possibly expensive) ML function over batch columns.
@@ -82,16 +93,32 @@ class UDF:
     # when the compiled path fails repeatedly. None == nothing to fall
     # back to (degrade-mode fault handling then quarantines instead).
     fallback_fn: Optional[Callable[[Dict[str, np.ndarray]], np.ndarray]] = None
-    # what one call counts, from its real rows' columns and the rows it
-    # launches after bucket padding (e.g. real against launched tokens);
-    # the worker adds it to the predicate's statistics entry
-    counts: Optional[
-        Callable[[Dict[str, np.ndarray], int], Dict[str, int]]] = None
+    # what one call counts, from its real rows' columns, the rows it
+    # launches after bucket padding (e.g. real against launched tokens)
+    # and the extras the call returned (``WithExtras``; {} when none); the
+    # worker adds it to the predicate's statistics entry
+    counts: Optional[Callable[[Dict[str, np.ndarray], int, Dict[str, np.ndarray]],
+                              Dict[str, int]]] = None
     degraded: bool = field(default=False, repr=False)
     _ready: bool = field(default=False, repr=False)
     # output dtype + trailing shape, learned from the first evaluation so
     # zero-row calls don't have to launch the kernel just for metadata
     _out_spec: Optional[tuple] = field(default=None, repr=False)
+    # extras of the last call made on each thread: a worker evaluates and
+    # then counts on its own thread, and workers share the UDF
+    _extras: threading.local = field(default_factory=threading.local,
+                                     repr=False, compare=False)
+
+    def last_extras(self) -> Dict[str, np.ndarray]:
+        """The extras returned by this thread's last call ({} if none)."""
+        return getattr(self._extras, "value", {})
+
+    def _outputs(self, out):
+        if isinstance(out, WithExtras):
+            self._extras.value = out.extras
+            return out.outputs
+        self._extras.value = {}
+        return out
 
     def ensure_ready(self) -> None:
         if not self._ready:
@@ -166,7 +193,7 @@ class UDF:
                     c: np.zeros((1,) + v.shape[1:], v.dtype)
                     for c, v in cols.items()
                 }
-                probe = fn(probe_cols)
+                probe = self._outputs(fn(probe_cols))
                 if probe is None:
                     # cache a sentinel so fn(None) doesn't re-probe forever
                     self._out_spec = (np.dtype(np.float64), ())
@@ -177,9 +204,9 @@ class UDF:
             dtype, trailing = self._out_spec
             return np.zeros((0,) + tuple(trailing), dtype)
         if not self.bucket:
-            out = np.asarray(fn(cols))
+            out = np.asarray(self._outputs(fn(cols)))
         else:
-            out = np.asarray(fn(cols))[:rows]
+            out = np.asarray(self._outputs(fn(cols)))[:rows]
         if out.ndim:
             self._out_spec = (out.dtype, out.shape[1:])
         return out

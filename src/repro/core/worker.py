@@ -178,10 +178,12 @@ def _evaluate_with_cache(pred, batch, data, *, cache, stats, faults=None,
 
 
 def _record_counts(pred, stats, computed_rows: int, compute_data) -> None:
-    """What the UDF counts of the rows it computed (``UDF.counts``)."""
+    """What the UDF counts of the rows it computed (``UDF.counts``), with
+    the extras its call on this thread returned."""
     if pred.udf.counts is not None:
         stats[pred.name].add_counts(pred.udf.counts(
-            compute_data, pred.udf.launched_rows(computed_rows)))
+            compute_data, pred.udf.launched_rows(computed_rows),
+            pred.udf.last_extras()))
 
 
 def _sim_cost(pred, computed_rows: int, data, wall: float) -> float:

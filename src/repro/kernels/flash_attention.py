@@ -1,14 +1,16 @@
 """Blocked causal flash attention (Pallas TPU), with GQA + sliding window.
 
-Layout: q/k/v flattened to (B*H, S, D) / (B*Hkv, S, D); grid
+Layout: q/k flattened to (B*H, S, D) / (B*Hkv, S, D), v to (B*Hkv, S, Dv)
+(latent attention's values are narrower than its queries and keys); grid
 (BH, num_q_blocks, num_kv_blocks) with the kv dimension innermost
 ("arbitrary" semantics) carrying the online-softmax state (m, l, acc) in VMEM
 scratch. Causal / sliding-window blocks that are fully masked are skipped
 with ``pl.when`` so the kernel does ~half (causal) or O(window) work.
 
-VMEM working set per program: q block (Bq, D) + k/v blocks (Bk, D) each +
-acc (Bq, D) f32 + stats — with Bq=Bk=128, D<=256 this is < 0.5 MB, far under
-the ~16 MB v5e VMEM budget; MXU contractions are (128, D)x(D, 128).
+VMEM working set per program: q and k blocks (Bq, D), (Bk, D), a v block
+(Bk, Dv), acc (Bq, Dv) f32 + stats — with Bq=Bk=128, D, Dv <= 256 this is
+< 0.5 MB, far under the ~16 MB v5e VMEM budget; MXU contractions are
+(128, D)x(D, 128) and (128, 128)x(128, Dv).
 """
 from __future__ import annotations
 
@@ -43,7 +45,7 @@ def _flash_kernel(
     def _compute():
         q = q_ref[0].astype(jnp.float32)          # (Bq, D)
         k = k_ref[0].astype(jnp.float32)          # (Bk, D)
-        v = v_ref[0].astype(jnp.float32)          # (Bk, D)
+        v = v_ref[0].astype(jnp.float32)          # (Bk, Dv)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale                                  # (Bq, Bk)
@@ -91,7 +93,7 @@ def _flash_kernel(
 def flash_attention_bhsd(
     q: jax.Array,   # (BH, Sq, D)
     k: jax.Array,   # (BHkv, Sk, D)
-    v: jax.Array,   # (BHkv, Sk, D)
+    v: jax.Array,   # (BHkv, Sk, Dv)
     *,
     group: int,     # H // Hkv
     causal: bool = True,
@@ -102,7 +104,7 @@ def flash_attention_bhsd(
     interpret: bool | None = None,
 ) -> jax.Array:
     bh, sq, d = q.shape
-    sk = k.shape[1]
+    sk, dv = k.shape[1], v.shape[2]
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
     assert sq % block_q == 0 and sk % block_k == 0, (sq, sk, block_q, block_k)
@@ -122,12 +124,12 @@ def flash_attention_bhsd(
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, qi, ki, g=group: (b // g, ki, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, qi, ki, g=group: (b // g, ki, 0)),
+            pl.BlockSpec((1, block_k, dv), lambda b, qi, ki, g=group: (b // g, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
+        out_specs=pl.BlockSpec((1, block_q, dv), lambda b, qi, ki: (b, qi, 0)),
+        out_shape=jax.ShapeDtypeStruct((bh, sq, dv), q.dtype),
         scratch_shapes=[
-            launch.VMEM((block_q, d), jnp.float32),
+            launch.VMEM((block_q, dv), jnp.float32),
             launch.VMEM((block_q, 1), jnp.float32),
             launch.VMEM((block_q, 1), jnp.float32),
         ],

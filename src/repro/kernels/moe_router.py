@@ -1,8 +1,10 @@
 """Fused MoE top-k gating (Pallas TPU).
 
-softmax over experts + iterative top-k (k is small and static: 2 for both
-assigned MoE archs) + renormalization, in one VMEM-resident pass over the
-token block. Grid (num_token_blocks,).
+softmax over experts + iterative greedy top-k (k is small and static: 2 for
+arctic and grok, 6 for DeepSeek-V2-Lite) + optional renormalization of the
+k weights (on for arctic and grok; off for DeepSeek-V2, whose
+``norm_topk_prob`` is false), in one VMEM-resident pass over the token
+block. Grid (num_token_blocks,).
 """
 from __future__ import annotations
 
@@ -17,7 +19,7 @@ from repro.kernels import launch
 NEG_INF = -1e30
 
 
-def _router_kernel(logits_ref, w_ref, idx_ref, *, k: int):
+def _router_kernel(logits_ref, w_ref, idx_ref, *, k: int, renormalize: bool):
     logits = logits_ref[...].astype(jnp.float32)          # (Bt, E)
     probs = jax.nn.softmax(logits, axis=-1)
 
@@ -35,7 +37,8 @@ def _router_kernel(logits_ref, w_ref, idx_ref, *, k: int):
         idxs.append(idx)
 
     w = jnp.stack(ws, axis=-1)                            # (Bt, k)
-    w = w / jnp.sum(w, axis=-1, keepdims=True)
+    if renormalize:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
     w_ref[...] = w.astype(w_ref.dtype)
     idx_ref[...] = jnp.stack(idxs, axis=-1).astype(jnp.int32)
 
@@ -44,6 +47,7 @@ def moe_router_tk(
     logits: jax.Array,  # (T, E)
     k: int,
     *,
+    renormalize: bool = True,
     block_t: int = 1024,
     interpret: bool | None = None,
 ):
@@ -52,7 +56,7 @@ def moe_router_tk(
     assert t % block_t == 0, (t, block_t)
     nt = t // block_t
 
-    kernel = functools.partial(_router_kernel, k=k)
+    kernel = functools.partial(_router_kernel, k=k, renormalize=renormalize)
     w, idx = launch.pallas_call(
         kernel,
         name="moe_router",

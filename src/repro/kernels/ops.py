@@ -19,6 +19,7 @@ import jax.numpy as jnp
 
 from repro.kernels import ref
 from repro.kernels.decode_attention import decode_attention_bkgd
+from repro.kernels.expert_ffn import expert_ffn_sorted
 from repro.kernels.flash_attention import flash_attention_bhsd
 from repro.kernels.hsv_color import hsv_color_hist
 from repro.kernels.launch import resolve_impl as _resolve
@@ -31,10 +32,11 @@ from repro.kernels.ssd import ssd_bhcp
 def flash_attention(
     q: jax.Array,   # (B, S, H, D)
     k: jax.Array,   # (B, S, Hkv, D)
-    v: jax.Array,   # (B, S, Hkv, D)
+    v: jax.Array,   # (B, S, Hkv, Dv)
     *,
     causal: bool = True,
     window: int = 0,
+    scale: float | None = None,   # None: D ** -0.5
     impl: str = "auto",
     block_q: int = 128,
     block_k: int = 128,
@@ -44,10 +46,10 @@ def flash_attention(
     impl = _resolve(impl)
     if impl == "xla":
         return ref.mha_attention(q, k, v, causal=causal, window=window,
-                                 chunk_q=chunk_q, unroll=unroll)
+                                 scale=scale, chunk_q=chunk_q, unroll=unroll)
 
     b, s, h, d = q.shape
-    hkv = k.shape[2]
+    hkv, dv = k.shape[2], v.shape[3]
     group = h // hkv
     # pad seq to a block multiple; causal masking makes tail padding inert for
     # the valid rows (padded q rows are sliced off).
@@ -63,13 +65,13 @@ def flash_attention(
     sp = s + pad
     qf = qp.transpose(0, 2, 1, 3).reshape(b * h, sp, d)
     kf = kp.transpose(0, 2, 1, 3).reshape(b * hkv, sp, d)
-    vf = vp.transpose(0, 2, 1, 3).reshape(b * hkv, sp, d)
+    vf = vp.transpose(0, 2, 1, 3).reshape(b * hkv, sp, dv)
     of = flash_attention_bhsd(
         qf, kf, vf,
-        group=group, causal=causal, window=window,
+        group=group, causal=causal, window=window, scale=scale,
         block_q=min(block_q, sp), block_k=min(block_k, sp),
     )
-    out = of.reshape(b, h, sp, d).transpose(0, 2, 1, 3)
+    out = of.reshape(b, h, sp, dv).transpose(0, 2, 1, 3)
     return out[:, :s]
 
 
@@ -177,11 +179,31 @@ def hsv_color_classify(
     return hist, jnp.argmax(hist, axis=-1)
 
 
-def moe_topk_router(logits: jax.Array, k: int, *, impl: str = "auto", block_t: int = 1024):
+def moe_topk_router(logits: jax.Array, k: int, *, renormalize: bool = True,
+                    impl: str = "auto", block_t: int = 1024):
     impl = _resolve(impl)
     if impl == "xla":
-        return ref.moe_topk_router(logits, k)
+        return ref.moe_topk_router(logits, k, renormalize=renormalize)
     t = logits.shape[0]
     return moe_router_tk(
-        logits, k, block_t=min(block_t, t)
+        logits, k, renormalize=renormalize, block_t=min(block_t, t)
     )
+
+
+def expert_ffn(
+    x: jax.Array,        # (R, D) rows grouped by expert on tile boundaries
+    w_gate: jax.Array,   # (E, D, F)
+    w_up: jax.Array,     # (E, D, F)
+    w_down: jax.Array,   # (E, F, D)
+    offsets: jax.Array,  # (E + 1,) int32 from expert_ffn.padded_offsets
+    *,
+    block_rows: int = 128,
+    impl: str = "auto",
+) -> jax.Array:
+    """Grouped SwiGLU of each group's rows by its expert's weights. Rows of
+    ``x`` past ``offsets[E]`` come back unspecified on the Pallas path."""
+    impl = _resolve(impl)
+    if impl == "xla":
+        return ref.expert_ffn(x, w_gate, w_up, w_down, offsets)
+    return expert_ffn_sorted(x, w_gate, w_up, w_down, offsets,
+                             block_rows=block_rows)

@@ -22,11 +22,13 @@ def _gqa_expand(k: jax.Array, num_heads: int) -> jax.Array:
     return jnp.repeat(k, group, axis=2)
 
 
-def _attn_dense(q, k, v, *, causal, window, q_offset, k_offset=0, kv_len=None):
-    """One dense attention tile; q (B,Sq,H,D) vs k/v (B,Sk,H,D) fp32 math."""
+def _attn_dense(q, k, v, *, causal, window, q_offset, k_offset=0, kv_len=None,
+                scale=None):
+    """One dense attention tile; q (B,Sq,H,D) vs k (B,Sk,H,D), v (B,Sk,H,Dv),
+    fp32 math."""
     b, sq, h, d = q.shape
     sk = k.shape[1]
-    scale = 1.0 / np.sqrt(d)
+    scale = 1.0 / np.sqrt(d) if scale is None else scale
     logits = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32), k.astype(jnp.float32))
     logits *= scale
     qpos = jnp.arange(sq)[:, None] + q_offset
@@ -52,10 +54,11 @@ Q_CHUNK = 512  # XLA-path q blocking: bounds the live S x S score tile
 def mha_attention(
     q: jax.Array,  # (B, Sq, H, D)
     k: jax.Array,  # (B, Sk, Hkv, D)
-    v: jax.Array,  # (B, Sk, Hkv, D)
+    v: jax.Array,  # (B, Sk, Hkv, Dv)
     *,
     causal: bool = True,
     window: int = 0,            # >0: sliding window (causal)
+    scale: float | None = None,  # softmax scale; None: D ** -0.5
     q_offset: int = 0,          # absolute position of q[0] (for decode/chunks)
     kv_len: jax.Array | None = None,  # (B,) valid kv length (masks the rest)
     chunk_q: int = Q_CHUNK,     # 0 disables chunking (dense)
@@ -80,7 +83,7 @@ def mha_attention(
 
     if chunk_q <= 0 or sq <= chunk_q or sq % chunk_q != 0:
         return _attn_dense(q, k, v, causal=causal, window=window,
-                           q_offset=q_offset, kv_len=kv_len)
+                           q_offset=q_offset, kv_len=kv_len, scale=scale)
 
     nchunks = sq // chunk_q
     qc = q.reshape(b, nchunks, chunk_q, h, d)
@@ -95,10 +98,11 @@ def mha_attention(
             return _attn_dense(
                 qb, kb, vb, causal=causal, window=window,
                 q_offset=q_offset + ci * chunk_q, k_offset=start, kv_len=kv_len,
+                scale=scale,
             )
         return _attn_dense(
             qb, k, v, causal=causal, window=window,
-            q_offset=q_offset + ci * chunk_q, kv_len=kv_len,
+            q_offset=q_offset + ci * chunk_q, kv_len=kv_len, scale=scale,
         )
 
     if unroll:
@@ -107,7 +111,7 @@ def mha_attention(
     else:
         fn = jax.checkpoint(lambda args: one(*args))
         out = jax.lax.map(fn, (jnp.moveaxis(qc, 1, 0), jnp.arange(nchunks)))
-    return jnp.moveaxis(out, 0, 1).reshape(b, sq, h, d)
+    return jnp.moveaxis(out, 0, 1).reshape(b, sq, h, v.shape[-1])
 
 
 def decode_attention(
@@ -339,9 +343,30 @@ def hsv_color_classify(crops: jax.Array, ranges: jax.Array | None = None):
 # --------------------------------------------------------------------------- #
 # MoE top-k router                                                             #
 # --------------------------------------------------------------------------- #
-def moe_topk_router(logits: jax.Array, k: int):
-    """(T, E) -> (weights (T,k) renormalized softmax, idx (T,k) int32)."""
+def moe_topk_router(logits: jax.Array, k: int, *, renormalize: bool = True):
+    """(T, E) -> (weights (T,k), idx (T,k) int32): the k largest softmax
+    probabilities, renormalized to sum to 1 when ``renormalize``."""
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     vals, idx = jax.lax.top_k(probs, k)
-    weights = vals / jnp.sum(vals, axis=-1, keepdims=True)
+    weights = vals / jnp.sum(vals, axis=-1, keepdims=True) if renormalize else vals
     return weights.astype(logits.dtype), idx.astype(jnp.int32)
+
+
+# --------------------------------------------------------------------------- #
+# grouped expert FFN (kernels/expert_ffn.py)                                   #
+# --------------------------------------------------------------------------- #
+def expert_ffn(x, w_gate, w_up, w_down, offsets):
+    """Rows grouped by expert at ``offsets`` (E + 1,), as the kernel takes
+    them: row r of group e -> down_e(silu(x_r gate_e) * (x_r up_e)), with
+    float32 accumulation; rows outside every group -> 0. Dense over the
+    experts (each row is computed by every expert and masked)."""
+    rows = jnp.arange(x.shape[0])
+    out = jnp.zeros(x.shape, jnp.float32)
+    for e in range(w_gate.shape[0]):
+        g = jnp.dot(x, w_gate[e], preferred_element_type=jnp.float32)
+        u = jnp.dot(x, w_up[e], preferred_element_type=jnp.float32)
+        h = (jax.nn.silu(g) * u).astype(x.dtype)
+        y = jnp.dot(h, w_down[e], preferred_element_type=jnp.float32)
+        mine = (rows >= offsets[e]) & (rows < offsets[e + 1])
+        out = jnp.where(mine[:, None], y, out)
+    return out.astype(x.dtype)

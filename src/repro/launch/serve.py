@@ -567,60 +567,82 @@ class QueryService:
 
 
 # ----------------------------- single-query CLI ----------------------------- #
-def llm_scorer(cfg, params):
-    """Review scorer ``tokens -> scores``: a decoder forward, then the mean
-    pooled log-probability of food words minus that of service words.
-
-    ``params`` is bound as an argument of the jitted function, not closed
-    over, so the weights are never baked into the program as constants."""
-    import functools
-
+def llm_score_fn(cfg):
+    """Jitted review scorer ``(params, tokens) -> (scores, extras)``: the
+    family's forward (``models/registry.model_api``), then the mean pooled
+    log-probability of food words minus that of service words over each
+    row's real tokens. ``extras`` is what the forward returned beside the
+    logits for the scorer to report (the DeepSeek family's per-layer expert
+    histogram of real assignments); {} for a family with none (the MoE
+    family's auxiliary value is a training loss, and is dropped)."""
     import jax
     import jax.numpy as jnp
 
     from repro.data.text import FOOD_WORDS, SERVICE_WORDS
-    from repro.models import transformer as tf
+    from repro.models.registry import model_api
 
+    api = model_api(cfg)
     food = jnp.asarray(FOOD_WORDS)
     service = jnp.asarray(SERVICE_WORDS)
 
     @jax.jit
     def score(params, tokens):  # tokens: (rows, MAX_LEN) int32, 0-padded
-        batch = {"tokens": tokens, "labels": tokens}
-        logits = tf.forward(cfg, params, batch)  # (rows, L, V)
-        mask = (tokens > 0)[..., None].astype(logits.dtype)
+        real = tokens > 0
+        batch = {"tokens": tokens, "labels": tokens, "token_mask": real}
+        out = api.forward(cfg, params, batch)
+        logits, aux = out if isinstance(out, tuple) else (out, None)  # (rows, L, V)
+        mask = real[..., None].astype(logits.dtype)
         pooled = (jax.nn.log_softmax(logits.astype(jnp.float32), -1) * mask).sum(1)
-        return pooled[:, food].mean(-1) - pooled[:, service].mean(-1)
+        scores = pooled[:, food].mean(-1) - pooled[:, service].mean(-1)
+        return scores, (aux if isinstance(aux, dict) else {})
 
-    return functools.partial(score, params)
+    return score
+
+
+def llm_scorer(cfg, params):
+    """``tokens -> scores`` of ``llm_score_fn`` with ``params`` bound as an
+    argument of the jitted function, not closed over, so the weights are
+    never baked into the program as constants."""
+    score = llm_score_fn(cfg)
+    return lambda tokens: score(params, tokens)[0]
 
 
 def build_llm_udf(arch: str = "smollm-135m", params=None, cfg=None):
-    """The LLM(...) predicate: a real decoder forward + token-pool scoring."""
+    """The LLM(...) predicate: a real decoder forward + token-pool scoring.
+    Besides real and launched tokens it counts what the forward reports:
+    for the DeepSeek family, the expert loads
+    (``models/deepseek.expert_counts``)."""
     import jax
     import jax.numpy as jnp
 
     from repro.configs import get_config
-    from repro.core.udf import D2H_SPAN, H2D_SPAN, LAUNCH_SPAN, UDF
+    from repro.core.udf import D2H_SPAN, H2D_SPAN, LAUNCH_SPAN, UDF, WithExtras
+    from repro.models import deepseek
     from repro.models.registry import model_api
 
     cfg = cfg or get_config(arch).reduce_for_smoke()
     if params is None:
         params = model_api(cfg).init_params(cfg, jax.random.key(0))
-    score = llm_scorer(cfg, params)
+    score = llm_score_fn(cfg)
 
     def fn(data):
         with kernel_launch.span(H2D_SPAN):
             tokens = jnp.asarray(data["tokens"])
         with kernel_launch.span(LAUNCH_SPAN):
-            scores = score(tokens)
+            scores, extras = score(params, tokens)
         with kernel_launch.span(D2H_SPAN):
-            return np.asarray(scores)
+            if not extras:
+                return np.asarray(scores)
+            return WithExtras(np.asarray(scores),
+                              {k: np.asarray(v) for k, v in extras.items()})
 
-    def counts(data, launched_rows):
+    def counts(data, launched_rows, extras):
         tokens = data["tokens"]
-        return {"tokens_real": int((tokens > 0).sum()),
-                "tokens_launched": launched_rows * tokens.shape[1]}
+        out = {"tokens_real": int((tokens > 0).sum()),
+               "tokens_launched": launched_rows * tokens.shape[1]}
+        if "expert_hist" in extras:
+            out.update(deepseek.expert_counts(extras["expert_hist"]))
+        return out
 
     return UDF(
         "LLM", fn, columns=("tokens",), resource="tpu:0",

@@ -1,4 +1,10 @@
-"""MoE decoder family (arctic-480b, grok-1-314b).
+"""MoE decoder family (arctic-480b, grok-1-314b): GQA attention, experts
+behind a capacity-bounded dispatch, for training, prefill and decode.
+
+DeepSeek-V2 (``models/deepseek.py``) shares only the top-k routing
+(``kernels/ref.moe_topk_router``, there without renormalisation); its
+scorer needs every assignment computed, so it dispatches droplessly
+through the grouped expert kernel instead of the capacity below.
 
 Dispatch design (DESIGN.md §5): activations are TP-replicated across the
 "model" axis, so expert dispatch needs NO all-to-all — a shard_map over

@@ -4,6 +4,8 @@ Every module satisfies the uniform API:
   param_shapes, param_logical, init_params, param_count, active_param_count,
   loss_fn, make_train_step, prefill, decode_step, input_specs, cache_shapes,
   roofline_units
+except ``deepseek``, which has the forward pass a scorer needs:
+  param_shapes, init_params, param_count, active_param_count, forward
 """
 from __future__ import annotations
 
@@ -11,11 +13,12 @@ from types import ModuleType
 
 
 def family_module(family: str) -> ModuleType:
-    from repro.models import encdec, hybrid, moe, ssm, transformer, vlm
+    from repro.models import deepseek, encdec, hybrid, moe, ssm, transformer, vlm
 
     table = {
         "dense": transformer,
         "moe": moe,
+        "deepseek": deepseek,
         "encdec": encdec,
         "hybrid": hybrid,
         "ssm": ssm,

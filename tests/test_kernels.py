@@ -44,6 +44,23 @@ def test_flash_attention_sliding_window(rng, window):
        ops.flash_attention(q, k, v, window=window, impl="xla"))
 
 
+@pytest.mark.parametrize("b,s,h,d,dv", [
+    (1, 128, 4, 48, 32),    # latent attention: q/k wider than v
+    (2, 200, 2, 24, 16),    # and the pad path
+    (1, 256, 2, 32, 64),    # v wider than q/k
+])
+def test_flash_attention_value_width(rng, b, s, h, d, dv):
+    q = jnp.asarray(rng.standard_normal((b, s, h, d)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((b, s, h, d)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((b, s, h, dv)), jnp.float32)
+    scale = 0.7 * d ** -0.5
+    got = ops.flash_attention(q, k, v, scale=scale, impl="pallas")
+    assert got.shape == (b, s, h, dv)
+    ok(got, ops.flash_attention(q, k, v, scale=scale, impl="xla"))
+    ok(ref.mha_attention(q, k, v, scale=scale, chunk_q=64),
+       ref.mha_attention(q, k, v, scale=scale, chunk_q=0), TOL_TIGHT)
+
+
 def test_xla_chunked_matches_dense(rng):
     """The memory-bounded chunked XLA path is exact vs dense."""
     b, s, h, hkv, d = 1, 1024, 2, 1, 32
@@ -208,3 +225,52 @@ def test_moe_router(rng, t, e, k):
     assert (np.asarray(i1) == np.asarray(i2)).all()
     # weights renormalized
     np.testing.assert_allclose(np.asarray(w1.sum(-1)), 1.0, rtol=1e-5)
+
+
+@pytest.mark.parametrize("t,e,k", [(64, 64, 6), (128, 16, 6), (32, 8, 3)])
+def test_moe_router_without_renormalization(rng, t, e, k):
+    """DeepSeek-V2's gate: the k largest softmax probabilities as they are."""
+    logits = jnp.asarray(rng.standard_normal((t, e)), jnp.float32)
+    w1, i1 = ops.moe_topk_router(logits, k, renormalize=False, impl="xla")
+    w2, i2 = ops.moe_topk_router(logits, k, renormalize=False, impl="pallas", block_t=16)
+    ok(w2, w1, TOL_TIGHT)
+    assert (np.asarray(i1) == np.asarray(i2)).all()
+    probs = np.asarray(jax.nn.softmax(logits, -1))
+    np.testing.assert_allclose(np.asarray(w1), np.take_along_axis(probs, np.asarray(i1), 1),
+                               rtol=1e-6)
+    assert (np.asarray(w1.sum(-1)) < 1.0).all()
+
+
+# ------------------------------ grouped expert FFN ------------------------- #
+@pytest.mark.parametrize("sizes", [
+    (0, 300, 5, 0, 128, 1),      # empty experts, partial last tiles, a full tile
+    (0, 0, 260, 0, 0, 0),        # one expert holds every row
+    (1, 1, 1, 1, 1, 1),          # every group a single row of its tile
+])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_expert_ffn(rng, sizes, dtype):
+    from repro.kernels.expert_ffn import padded_offsets
+
+    e, d, f, bm = len(sizes), 128, 256, 128
+    sizes = jnp.asarray(sizes, jnp.int32)
+    offsets = padded_offsets(sizes, bm)
+    r = int(offsets[-1]) + 2 * bm       # two tiles past the real rows
+    rows = np.arange(r)
+    starts = np.asarray(offsets[:-1])
+    real = ((rows[:, None] >= starts) & (rows[:, None] < starts + np.asarray(sizes))).any(1)
+    x = jnp.asarray(rng.standard_normal((r, d)) * real[:, None], dtype)
+    wg = jnp.asarray(rng.standard_normal((e, d, f)) * d ** -0.5, dtype)
+    wu = jnp.asarray(rng.standard_normal((e, d, f)) * d ** -0.5, dtype)
+    wd = jnp.asarray(rng.standard_normal((e, f, d)) * f ** -0.5, dtype)
+    got = ops.expert_ffn(x, wg, wu, wd, offsets, block_rows=bm, impl="pallas")
+    want = ops.expert_ffn(x, wg, wu, wd, offsets, block_rows=bm, impl="xla")
+    tol = TOL if dtype == jnp.float32 else dict(rtol=8e-2, atol=8e-2)
+    inside = rows < int(offsets[-1])
+    ok(np.asarray(got, np.float32)[inside], np.asarray(want, np.float32)[inside], tol)
+    # each real row against its own expert's SwiGLU, in float32
+    xf, gf, uf, df = (np.asarray(a, np.float32) for a in (x, wg, wu, wd))
+    for j in np.nonzero(real)[0][:: 7]:
+        ex = int(np.searchsorted(starts, j, side="right") - 1)
+        g, u = xf[j] @ gf[ex], xf[j] @ uf[ex]
+        y = (g / (1 + np.exp(-g)) * u) @ df[ex]
+        ok(np.asarray(got, np.float32)[j], y, tol)

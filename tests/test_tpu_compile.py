@@ -15,7 +15,9 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
+from repro.configs.deepseek_v2_lite import CONFIG as DEEPSEEK
 from repro.kernels.decode_attention import decode_attention_bkgd
+from repro.kernels.expert_ffn import expert_ffn_sorted
 from repro.kernels.flash_attention import flash_attention_bhsd
 from repro.kernels.hsv_color import hsv_color_hist
 from repro.kernels.moe_router import moe_router_tk
@@ -99,8 +101,36 @@ def _moe_router():
             [((4096, c.num_experts), F32)])
 
 
+def _flash_mla():
+    c = DEEPSEEK
+    b, qk = 16, c.qk_nope_head_dim + c.qk_rope_head_dim
+    bh = b * c.num_heads
+    return (lambda q, k, v: flash_attention_bhsd(
+                q, k, v, group=1, scale=qk ** -0.5, interpret=False),
+            [((bh, MAX_LEN, qk), BF16), ((bh, MAX_LEN, qk), BF16),
+             ((bh, MAX_LEN, c.v_head_dim), BF16)])
+
+
+def _expert_ffn():
+    c, bm = DEEPSEEK, 128
+    e, d, f = c.num_experts, c.d_model, c.moe_d_ff
+    r = 16 * MAX_LEN * c.num_experts_per_tok + e * bm  # the scorer's static rows
+    return (lambda x, g, u, w, off: expert_ffn_sorted(
+                x, g, u, w, off, block_rows=bm, interpret=False),
+            [((r, d), BF16), ((e, d, f), BF16), ((e, d, f), BF16),
+             ((e, f, d), BF16), ((e + 1,), I32)])
+
+
+def _moe_router_k6():
+    c = DEEPSEEK
+    return (lambda lg: moe_router_tk(lg, c.num_experts_per_tok, renormalize=False,
+                                     interpret=False),
+            [((16 * MAX_LEN, c.num_experts), F32)])
+
+
 @pytest.mark.parametrize("case", [_hsv, _flash, _decode, _rglru, _ssd,
-                                  _moe_router],
+                                  _moe_router, _flash_mla, _expert_ffn,
+                                  _moe_router_k6],
                          ids=lambda f: f.__name__.strip("_"))
 def test_kernel_compiles_for_v5e(one_chip, case):
     fn, shapes = case()
