@@ -21,7 +21,7 @@ import numpy as np
 
 from chipbench import loadgen, spec
 
-TRACE_DIR = spec.REPO / ".chipbench" / "trace"
+TRACE_ROOT = spec.REPO / ".chipbench" / "trace"
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
@@ -125,6 +125,12 @@ class Run:
         return self.share(flops / self.peaks["bf16_flops_per_s"])
 
 
+def trace_dir() -> str:
+    """This process's own directory for its trace, so that traced runs at
+    once (rehearsals under several test workers) never share one."""
+    return str(TRACE_ROOT / str(os.getpid()))
+
+
 def parse(argv):
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--workload", required=True)
@@ -192,11 +198,12 @@ def main(argv=None, *, started: Optional[float] = None, small: bool = False,
     gc.freeze()
     trace = None
     if args.trace:
-        shutil.rmtree(TRACE_DIR, ignore_errors=True)
+        log_dir = trace_dir()
+        shutil.rmtree(log_dir, ignore_errors=True)
         options = jax.profiler.ProfileOptions()
         options.python_tracer_level = 0     # no Python call tracing
         options.host_tracer_level = 1       # the benchmark's spans, little else
-        jax.profiler.start_trace(str(TRACE_DIR), profiler_options=options)
+        jax.profiler.start_trace(log_dir, profiler_options=options)
     dep.start_window()
     start = time.monotonic()
     with jax.profiler.TraceAnnotation("cb.window"):
@@ -211,8 +218,8 @@ def main(argv=None, *, started: Optional[float] = None, small: bool = False,
         jax.profiler.stop_trace()
         from chipbench import trace as tr
 
-        events = tr.read_xplane(str(TRACE_DIR))
-        shutil.rmtree(TRACE_DIR, ignore_errors=True)
+        events = tr.read_xplane(log_dir)
+        shutil.rmtree(log_dir, ignore_errors=True)
         window = [(a, b) for kind_, name, a, b in events
                   if kind_ == "span" and name == "cb.window"]
         if not window:

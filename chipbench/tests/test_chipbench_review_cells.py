@@ -4,7 +4,8 @@ import pytest
 
 from chipbench import spec
 from chipbench_cellrun import (  # noqa: F401  (fixtures by name)
-    break_answers, check_rehearsal, constant_answers, drop_half, run_cell)
+    break_answers, check_rehearsal, constant_answers, drop_half, instant_answers,
+    planned_queries, run_cell)
 
 CELL = 'review-smollm135m.scan'
 
@@ -18,6 +19,13 @@ def _with_limit(config, key, limit):
 def test_review_rehearsal(mix, capsys):
     check_rehearsal(run_cell(f"review-smollm135m.{mix}", capsys,
                              trace=int(mix == 'interactive')))
+
+
+def test_review_plan_outlasts_instant_udfs(instant_answers, capsys):
+    """With a model that scores at once, the scan's closed-loop plan still
+    outlasts the window (the run would raise)."""
+    out = run_cell(CELL, capsys)
+    assert 1 <= out["attempted"] < planned_queries(CELL)
 
 
 @pytest.mark.parametrize("fault", ["break_answers", "drop_half"])

@@ -1,4 +1,5 @@
-"""The reduction from trace events to busy, idle, kernel and gap times."""
+"""The reduction from trace events to busy, idle, kernel and gap times, and
+traced runs at once keeping their traces apart."""
 import sys
 from pathlib import Path
 
@@ -7,7 +8,9 @@ import pytest
 REPO = Path(__file__).resolve().parents[2]
 sys.path[:0] = [str(REPO / "src"), str(REPO)]
 
+from chipbench import harness  # noqa: E402
 from chipbench import trace as tr  # noqa: E402
+from chipbench_cellrun import traced_rehearsal  # noqa: E402
 
 SAMPLE = Path(__file__).with_name("trace_sample.json.gz")
 
@@ -80,3 +83,24 @@ def test_recorded_chip_trace():
     gaps = s.idle_gaps(top=100)
     assert sum(g for _, g in gaps) == pytest.approx(s.idle_s(), rel=1e-9)
     assert s.idle_in("udf") + s.idle_outside(["udf"]) == pytest.approx(s.idle_s())
+
+
+def test_traced_rehearsals_at_once_keep_their_own_traces():
+    """Two traced rehearsals in one process pool, their traces open at
+    once: each reads its trace from a directory of its own process and
+    removes only that one."""
+    import multiprocessing
+    from concurrent.futures import ProcessPoolExecutor
+
+    ctx = multiprocessing.get_context("spawn")
+    with ctx.Manager() as manager, ProcessPoolExecutor(2, mp_context=ctx) as pool:
+        barrier = manager.Barrier(2)
+        futures = [pool.submit(traced_rehearsal, "review-smollm135m.scan", barrier)
+                   for _ in range(2)]
+        runs = [f.result(timeout=300) for f in futures]
+    assert runs[0][0] != runs[1][0]
+    for pid, dirs, out in runs:
+        assert dirs == [str(harness.TRACE_ROOT / str(pid))]
+        assert not Path(dirs[0]).exists()
+        assert out["correct"], out["check"]
+        assert "breakdown" in out and out["device"]["window_s"] > 0

@@ -4,7 +4,8 @@ import pytest
 
 from chipbench import spec
 from chipbench_cellrun import (  # noqa: F401  (fixtures by name)
-    break_answers, check_rehearsal, constant_answers, drop_half, run_cell)
+    break_answers, check_rehearsal, constant_answers, drop_half, instant_answers,
+    planned_queries, run_cell)
 
 CELL = 'uc1-lostdog.case1'
 
@@ -22,6 +23,15 @@ def test_uc1_rehearsal(mix, capsys):
 def test_uc1_queries_of_any_size(capsys):
     """Query sizes follow the mix's size law, not one fixed size."""
     check_rehearsal(run_cell(CELL, capsys, query_rows={"loguniform": [8, 40]}))
+
+
+@pytest.mark.parametrize("mix", ['case1', 'case2'])
+def test_uc1_plan_outlasts_instant_udfs(mix, instant_answers, capsys):
+    """With UDFs that answer at once, as fast as the query path around them
+    lets a query go, the closed-loop plan still outlasts the window: no
+    faster UDF can use it up (the run would raise)."""
+    out = run_cell(f"uc1-lostdog.{mix}", capsys)
+    assert 1 <= out["attempted"] < planned_queries(f"uc1-lostdog.{mix}")
 
 
 @pytest.mark.parametrize("fault", ["break_answers", "constant_answers", "drop_half"])
